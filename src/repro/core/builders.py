@@ -264,7 +264,7 @@ def build_train_step(
     b1: float = 0.9,
     b2: float = 0.999,
     eps: float = 1e-8,
-    use_kernel: Optional[bool] = None,
+    use_kernel: bool = False,
     mesh=None,
     partition: str = "div",
     hot_capacity: int = 4096,
@@ -304,10 +304,11 @@ def build_train_step(
 
     ``path=None`` honors the config knobs: ``cfg.placement`` if set, else
     ``cfg.sparse`` selects "sparse", otherwise "substrate".
-    ``use_kernel=None`` compiles the Pallas kernels on TPU and runs the
-    identical jnp reference elsewhere (interpret-mode kernels are a
-    correctness harness, far too slow for CPU training). The dense tower
-    always runs the substrate Adam (with optional warmup).
+    ``use_kernel=True`` runs the embedding row update through the Pallas
+    kernels where the placement has them (compiled on a TPU, interpret mode
+    elsewhere — a correctness harness, far too slow for CPU training); the
+    default is the jnp/XLA update on every backend. The dense tower always
+    runs the substrate Adam (with optional warmup).
     """
     from ..embed.store import store_for  # deferred: embed imports core
 

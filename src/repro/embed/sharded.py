@@ -38,10 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax >= 0.5
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401  (re-exported for the step builders)
+from jax.sharding import AxisType, Mesh
 
 from ..core.cowclip import cowclip_table
 from ..core.optim import decay_factor, sparse_adam_rows
@@ -350,3 +348,15 @@ def default_mesh():
     model-axis for data-axis parallelism."""
     n = jax.device_count()
     return jax.make_mesh((1, n), ("data", "model"))
+
+
+def auto_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis typed ``Auto``.
+
+    The sharded placements leave layout outside their ``shard_map`` to the
+    compiler's sharding propagation (e.g. ``export`` slices pad rows off a
+    row-sharded table). ``jax.make_mesh`` types axes ``Explicit`` by
+    default, under which such a slice is a type error, so the placements
+    retype whatever mesh they are given."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))

@@ -53,10 +53,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from ..core.cowclip import cowclip_rows
-from ..core.optim import decay_catchup_rows, sparse_adam_rows
-from ..kernels.cowclip import ref as cc_ref
-from ..kernels.cowclip import sparse as cc_sparse
+from ..core.optim import decay_catchup_rows
+from ..kernels.cowclip import ops as cc_ops
 from .sharded import RowShardPlan, shard_update
 
 
@@ -248,29 +246,6 @@ def rowgrad_slots(g_col: jnp.ndarray, ids_col: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _safe_local(uloc, counts, rows):
-    """In-range slot indices for the kernels' block index maps. On top of
-    ``safe_uids``'s pad-aliases-last-real-slot remap, clamp into the shard:
-    a shard that owns *no* batch ids has every count at 0, so safe_uids
-    returns the (out-of-range) pad value itself — the clamp makes those
-    all-pad reads hit row ``rows - 1`` instead, and the kernels' ``cnt > 0``
-    write guards keep them write-free."""
-    return jnp.minimum(cc_sparse.safe_uids(uloc, counts), rows - 1)
-
-
-def _gather_catchup_rows(w, m, v, ls, uloc, counts, t, *, use_kernel,
-                         interpret, **adam_kw):
-    """Gather touched rows from this shard and replay their pending decay
-    (through t-1). jnp oracle, or the Pallas kernel with local row indices
-    (``row_offset=0`` — indices are already shard-local here)."""
-    if not use_kernel:
-        return cc_ref.sparse_gather_catchup_reference(
-            w, m, v, ls, uloc, t, **adam_kw)
-    su = _safe_local(uloc, counts, w.shape[0])
-    return cc_sparse.sparse_gather_catchup(
-        w, m, v, ls[su], su, t, interpret=interpret, **adam_kw)
-
-
 def catchup_depth_slots(ls, uloc, counts, t):
     """Max pending-decay depth over this shard's touched slots at step ``t``
     — the ``aux["catchup_depth_max"]`` diagnostic. A slot touched last step
@@ -282,7 +257,7 @@ def catchup_depth_slots(ls, uloc, counts, t):
 
 
 def update_phase(w, m, v, ls, uloc, counts, overflow, g_slots, g_full,
-                 cnt_full, t, *, use_kernel, interpret, clip=True, r=1.0,
+                 cnt_full, t, *, use_kernel=False, clip=True, r=1.0,
                  zeta=1e-5, lr=1e-4, l2=1e-5, b1=0.9, b2=0.999, eps=1e-8):
     """Post-backward phase on one (field, group) shard, starting from the
     *raw* (w, m, v, ls) tensors — the forward never scatters into them
@@ -313,28 +288,13 @@ def update_phase(w, m, v, ls, uloc, counts, overflow, g_slots, g_full,
 
     def sparse_branch(_):
         with jax.named_scope("row_gather_catchup"):
-            w_rows, m_rows, v_rows = _gather_catchup_rows(
-                w, m, v, ls, uloc, counts, t, use_kernel=use_kernel,
-                interpret=interpret, **adam_kw)
+            w_rows, m_rows, v_rows = cc_ops.sparse_gather_catchup(
+                w, m, v, ls, uloc, t, use_kernel=use_kernel, **adam_kw)
         g_rows = g_slots if g_slots is not None else g_full[safe]
         with jax.named_scope("row_update_scatter"):
-            if use_kernel:
-                su = _safe_local(uloc, counts, rows)
-                w2, m2, v2 = cc_sparse.sparse_update_scatter(
-                    w, m, v, su, counts, w_rows, g_rows,
-                    m_rows, v_rows, t, r=r, zeta=zeta, clip=clip,
-                    interpret=interpret, **adam_kw)
-            else:
-                g32 = g_rows.astype(jnp.float32)
-                if clip:
-                    g32 = cowclip_rows(g32, w_rows, counts, r=r, zeta=zeta)
-                wn, mn, vn = sparse_adam_rows(
-                    g32, w_rows, m_rows, v_rows, t, **adam_kw)
-                w2 = w.at[uloc].set(wn.astype(w.dtype), mode="drop")
-                m2 = m.at[uloc].set(mn.astype(m.dtype), mode="drop")
-                v2 = v.at[uloc].set(vn.astype(v.dtype), mode="drop")
-        ls2 = ls.at[uloc].set(t.astype(ls.dtype), mode="drop")
-        return w2, m2, v2, ls2
+            return cc_ops.sparse_update_scatter(
+                w, m, v, ls, uloc, counts, w_rows, g_rows, m_rows, v_rows, t,
+                r=r, zeta=zeta, clip=clip, use_kernel=use_kernel, **adam_kw)
 
     if overflow is False:
         return sparse_branch(None)

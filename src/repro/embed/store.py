@@ -143,10 +143,15 @@ class EmbeddingStore:
         b1: float = 0.9,
         b2: float = 0.999,
         eps: float = 1e-8,
-        use_kernel: Optional[bool] = None,
+        use_kernel: bool = False,
         nonfinite_guard: bool = False,
     ) -> TrainStepBundle:
         """Build this placement's (step, init, flush, prepare) bundle.
+
+        ``use_kernel=True`` runs the embedding row update through the Pallas
+        kernels (repro.kernels.cowclip) on the placements that have them
+        (fused, sparse, sharded_sparse); the default is the jnp/XLA update
+        on every backend.
 
         ``nonfinite_guard`` wraps the step so a batch whose loss comes out
         NaN/Inf skips the entire update (params, moments, step counter),
@@ -175,12 +180,9 @@ class EmbeddingStore:
         b1: float = 0.9,
         b2: float = 0.999,
         eps: float = 1e-8,
-        use_kernel: Optional[bool] = None,
+        use_kernel: bool = False,
     ) -> TrainStepBundle:
         from ..train import loop as loop_lib  # deferred: train imports core
-
-        if use_kernel is None:
-            use_kernel = jax.default_backend() == "tpu"
 
         if self.placement == "dense" and self.kernel != "fused":
             tx = builders.build_optimizer(
@@ -239,7 +241,8 @@ class EmbeddingStore:
         # sharded / sharded_sparse
         from . import sharded as shard_lib
 
-        mesh = self.mesh if self.mesh is not None else shard_lib.default_mesh()
+        mesh = shard_lib.auto_mesh(
+            self.mesh if self.mesh is not None else shard_lib.default_mesh())
         if self.placement == "sharded_sparse":
             step, init, flush, prepare, export = (
                 loop_lib.make_sharded_sparse_train_step(

@@ -4,6 +4,16 @@ cowclip/ : fused CowClip + L2 + Adam embedding-row update (bandwidth-bound)
 wkv6/    : chunked RWKV-6 linear-attention scan (MXU-bound)
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper; interpret=True off-TPU), ref.py (pure-jnp oracle).
+wrapper), ref.py (pure-jnp oracle). The wrappers run the oracle unless the
+caller asks for the kernel (``use_kernel=True``); a kernel asked for runs
+compiled on a TPU and in Pallas interpret mode elsewhere.
 """
 
+import jax
+
+
+def interpret_off_tpu() -> bool:
+    """Pallas ``interpret`` flag for a kernel a caller asked for: off a TPU
+    the kernel body runs as plain jnp (a correctness harness, slow), on a
+    TPU it compiles."""
+    return jax.default_backend() != "tpu"

@@ -44,22 +44,24 @@ def _kernel(bc_ref, w_ref, g_ref, cnt_ref, m_ref, v_ref,
     g = g_ref[...].astype(jnp.float32)
     m = m_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    cnt = cnt_ref[...].astype(jnp.float32)          # [BLOCK_ROWS]
+    # counts arrive as a [BLOCK_ROWS, 1] column and the row norms keep their
+    # reduced axis, so every per-row scalar broadcasts over D as it is: the
+    # TPU compiler has no layout for reshaping a 1-D row vector to a column
+    cnt = cnt_ref[...].astype(jnp.float32)          # [BLOCK_ROWS, 1]
     bc1 = bc_ref[0, 0]                              # 1/(1-b1^t)
     bc2 = bc_ref[0, 1]                              # 1/(1-b2^t)
 
     if do_clip:
-        gnorm = jnp.sqrt(jnp.sum(g * g, axis=-1))   # [BLOCK_ROWS]
-        wnorm = jnp.sqrt(jnp.sum(w * w, axis=-1))
+        gnorm = jnp.sqrt(jnp.sum(g * g, axis=-1, keepdims=True))
+        wnorm = jnp.sqrt(jnp.sum(w * w, axis=-1, keepdims=True))
         clip_t = cnt * jnp.maximum(r * wnorm, zeta)
-        scale = jnp.minimum(1.0, clip_t / (gnorm + 1e-30))
-        g = g * scale[:, None]
+        g = g * jnp.minimum(1.0, clip_t / (gnorm + 1e-30))
 
     gl = g + l2 * w
     m2 = b1 * m + (1.0 - b1) * gl
     v2 = b2 * v + (1.0 - b2) * gl * gl
     upd = (m2 * bc1) / (jnp.sqrt(v2 * bc2) + eps)
-    touched = (cnt > 0.0)[:, None]
+    touched = cnt > 0.0
     w = jnp.where(touched, w - lr * upd, w * factor)
     m = jnp.where(touched, m2, m)
     v = jnp.where(touched, v2, v)
@@ -67,6 +69,12 @@ def _kernel(bc_ref, w_ref, g_ref, cnt_ref, m_ref, v_ref,
     w_out[...] = w.astype(w_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
     v_out[...] = v.astype(v_out.dtype)
+
+
+def default_block_rows(dim: int) -> int:
+    """Rows per block: ~2 MB of VMEM across the 7 resident [rows, D] f32
+    tiles, and a multiple of 8 (the TPU's sublane count)."""
+    return max(8, min(1024, (1 << 19) // max(dim, 1)))
 
 
 def cowclip_adam_update(
@@ -84,15 +92,17 @@ def cowclip_adam_update(
     b1: float = 0.9,
     b2: float = 0.999,
     eps: float = 1e-8,
+    clip: bool = True,
     block_rows: int = 0,
     interpret: bool = False,
 ):
-    """Fused CowClip+L2+Adam. Returns (w_new, m_new, v_new)."""
+    """Fused CowClip+L2+Adam. Returns (w_new, m_new, v_new).
+
+    ``block_rows`` defaults to ``default_block_rows(D)``; a block shorter
+    than the table is a multiple of 8 rows on a TPU, one as long is any
+    length."""
     vocab, dim = w.shape
-    if block_rows <= 0:
-        # target ~2 MB VMEM across the 7 resident [rows, D] f32 tiles
-        block_rows = max(8, min(1024, (1 << 19) // max(dim, 1)))
-    block_rows = min(block_rows, vocab)
+    block_rows = min(block_rows or default_block_rows(dim), vocab)
     n_blocks = pl.cdiv(vocab, block_rows)
 
     t = step.astype(jnp.float32)
@@ -104,11 +114,11 @@ def cowclip_adam_update(
         _kernel, r=r, zeta=zeta, lr=lr, l2=l2, b1=b1, b2=b2, eps=eps,
         # paper: 1-dim LR-stream tables are exempt from CowClip (matches
         # core.cowclip.cowclip_table and ref.py)
-        do_clip=dim >= 2,
+        do_clip=clip and dim >= 2,
         factor=decay_factor(lr, l2),
     )
     row_block = pl.BlockSpec((block_rows, dim), lambda i: (i, 0))
-    cnt_block = pl.BlockSpec((block_rows,), lambda i: (i,))
+    cnt_block = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
     bc_block = pl.BlockSpec((1, 2), lambda i: (0, 0))
 
     return pl.pallas_call(
@@ -122,4 +132,4 @@ def cowclip_adam_update(
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-    )(bc, w, g, cnt, m, v)
+    )(bc, w, g, cnt.reshape(vocab, 1), m, v)

@@ -3,48 +3,42 @@
 The dense fused kernel (``cowclip.py``) still streams the full ``[vocab,
 dim]`` table plus both Adam moments through HBM every step, although a batch
 touches only its unique ids. These kernels restrict the whole update to the
-``[n_unique, dim]`` gathered rows, making optimizer HBM traffic O(batch)
-instead of O(vocab) — the layout production CTR systems use
+``[cap, dim]`` rows of the batch's unique-id slots, making optimizer HBM
+traffic O(batch) instead of O(vocab) — the layout production CTR systems use
 (arXiv:2201.05500 §4, arXiv:2209.05310 §6).
 
 The logical pipeline is **gather -> lazy-decay catch-up -> CowClip -> Adam ->
-scatter**, split into two kernels only because the task-loss gradient is
-computed (by the model's backward pass) *between* the catch-up and the clip —
-the forward must see rows with their pending L2 decay applied or the two
-paths diverge:
+scatter**, split in two because the task-loss gradient is computed (by the
+model's backward pass) *between* the catch-up and the clip — the forward
+must see rows with their pending L2 decay applied or the two paths diverge:
 
-* ``sparse_gather_catchup``: one pass over unique rows; for each slot, DMA
-  the id's (w, m, v) row from HBM via a scalar-prefetched index map, apply
-  its missed decay-only steps in closed form — ``w *= (1 - lr*l2)**k`` for
-  k pending steps, O(1) in k, moments held (ids absent from a batch still
-  decay under coupled L2 — paper's zeta discussion) — and emit the
-  caught-up rows.
-* ``sparse_update_scatter``: one pass over unique rows; CowClip (per-id
-  count-scaled adaptive threshold) -> coupled L2 -> Adam on the row, written
-  straight back to the table's HBM row through an aliased output whose index
-  map scatters by uid. Rows of absent ids are never touched.
+* ``sparse_gather_catchup``: XLA gathers each slot's (w, m, v, last_step)
+  row into contiguous ``[cap, dim]`` slabs; the kernel applies every row's
+  missed decay-only steps in closed form — ``w *= (1 - lr*l2)**k`` for k
+  pending steps, O(1) in k, moments held (ids absent from a batch still
+  decay under coupled L2 — paper's zeta discussion).
+* ``sparse_update_scatter``: the dense fused kernel runs CowClip (per-id
+  count-scaled adaptive threshold) -> coupled L2 -> Adam on the slabs, and
+  XLA scatters the new rows back into the tables. Rows of absent ids are
+  never written.
 
-Pad-slot handling (capacity > n_unique): slot uids are remapped on the host
-to the **last real slot's uid** before entering a kernel, so every block
-index is in range; pad iterations skip their write (``counts == 0``) and,
-because consecutive grid steps then map the same output block, Pallas defers
-the single copy-out until the end — the real slot's value lands exactly
-once. The raw (out-of-range) uids are kept for the XLA-side ``mode='drop'``
-scatters (``last_step``) and the jnp reference.
+Layout: the kernels tile the slabs in ``(block_rows, dim)`` blocks — a
+multiple of 8 rows, or the whole slab — with per-slot scalars as
+``[cap, 1]`` columns. A kernel that moved one table row per grid step would
+need ``(1, dim)`` blocks or a one-row DMA out of HBM, and the TPU compiler
+accepts neither at CTR widths (dim 10 and 1 are not multiples of its
+(8, 128) tiling), so the row movement is XLA's gather and scatter.
 
-Grid = one row per step: gathered rows are not contiguous, so blocks cannot
-span slots. ``dim`` (10 for CTR) under-fills the 128-wide lanes; at
-production scale the win is ending O(vocab) HBM streaming, not lane
-utilization. All math f32, matching ``ref.py`` bit-for-bit in op order.
+Pad slots (capacity > n_unique, count 0) carry out-of-range uids: their
+gathered rows are garbage that nothing reads, and the scatter drops them
+(``mode="drop"``, with count-0 slots forced out of range).
 
-Shard-offset awareness: both kernels take a ``row_offset`` (second
-scalar-prefetch operand) subtracted from every uid inside the index maps,
-so a model-shard of a row-partitioned table (repro.embed.sharded_sparse)
-can feed *global* ids against its local ``[rows_per_shard, dim]`` block —
-the shard's base row never has to be materialized into the uid array.
-Offset-uid contract: after subtraction every *real* slot's row index must
-be in ``[0, rows)`` (guaranteed when the caller owns those ids); pad slots
-go through ``safe_uids`` first, which aliases them to a real (owned) slot.
+Shard-offset awareness: both functions take a ``row_offset`` subtracted
+from every uid before the gather and the scatter, so a model-shard of a
+row-partitioned table (repro.embed.sharded_sparse) can feed *global* ids
+against its local ``[rows_per_shard, dim]`` block. Every *real* slot's uid
+minus the offset must be in ``[0, rows)`` (guaranteed when the caller owns
+those ids).
 """
 
 from __future__ import annotations
@@ -54,48 +48,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .cowclip import cowclip_adam_update, default_block_rows
 
 
-def safe_uids(uids: jnp.ndarray, counts: jnp.ndarray) -> jnp.ndarray:
-    """Remap pad slots (count 0) to the last real slot's uid.
-
-    Keeps every kernel block index in range while preserving the
-    revisit-coalescing that makes pad slots free (see module docstring).
-    """
-    n_real = jnp.maximum(jnp.sum((counts > 0).astype(jnp.int32)), 1)
-    last_real = uids[n_real - 1]
-    return jnp.where(counts > 0, uids, last_real).astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# kernel A: gather + lazy-decay catch-up
-# ---------------------------------------------------------------------------
-
-
-def _catchup_kernel(uids_ref, off_ref, w_ref, m_ref, v_ref, ls_ref, lim_ref,
-                    w_out, m_out, v_out, *, factor):
-    del uids_ref, off_ref  # consumed by the index maps
-    w = w_ref[...].astype(jnp.float32)            # (1, dim)
-    ls = ls_ref[0]                                # row's last-updated step
-    lim = lim_ref[0]                              # catch up through this step
-
+def _catchup_kernel(lim_ref, w_ref, ls_ref, w_out, *, factor):
+    lim = lim_ref[0, 0]                           # catch up through this step
     # closed form: k pending decay-only steps collapse to one multiply
     # (w *= factor**k, moments untouched); k == 0 multiplies by exactly 1.0
     # so an already-caught-up row passes through bit-identically
-    k = jnp.maximum(lim - ls, 0).astype(jnp.float32)
+    k = jnp.maximum(lim - ls_ref[...], 0).astype(jnp.float32)   # [rows, 1]
     scale = jnp.where(k > 0, factor**k, 1.0)
-    w_out[...] = w * scale
-    m_out[...] = m_ref[...].astype(jnp.float32)
-    v_out[...] = v_ref[...].astype(jnp.float32)
+    w_out[...] = w_ref[...].astype(jnp.float32) * scale
 
 
 def sparse_gather_catchup(
     w: jnp.ndarray,           # [rows, dim] table (or one shard of it)
     m: jnp.ndarray,           # [rows, dim] Adam first moment
     v: jnp.ndarray,           # [rows, dim] Adam second moment
-    ls_rows: jnp.ndarray,     # [cap] int32 last_step gathered per slot
-    uids: jnp.ndarray,        # [cap] int32 in-range slot uids (safe_uids)
+    last_step: jnp.ndarray,   # [rows] int32 step each row was last updated
+    uids: jnp.ndarray,        # [cap] int32 slot uids (pads out of range)
     step: jnp.ndarray,        # scalar int32 t: catch rows up through t-1
     *,
     lr: float,
@@ -104,83 +76,39 @@ def sparse_gather_catchup(
     b2: float = 0.999,
     eps: float = 1e-8,
     row_offset=0,             # subtracted from uids: shard's first global row
+    block_rows: int = 0,
     interpret: bool = False,
 ):
-    """Fused gather + closed-form decay catch-up, O(1) in pending depth.
+    """Gather + closed-form decay catch-up, O(1) in pending depth.
     Returns f32 (w_rows, m_rows, v_rows); m/v rows are gathered unchanged
     (decay-only steps never move the Adam moments). b1/b2/eps are accepted
-    for hyper-dict compatibility with the update kernel."""
+    for hyper-dict compatibility with the update."""
     from ...core.optim import decay_factor
 
-    cap = uids.shape[0]
-    dim = w.shape[1]
-    lim = jnp.full((cap,), step - 1, jnp.int32)
-    off = jnp.full((1,), row_offset, jnp.int32)
-
-    row_by_uid = pl.BlockSpec(
-        (1, dim), lambda i, uids_ref, off_ref: (uids_ref[i] - off_ref[0], 0))
-    row_by_slot = pl.BlockSpec((1, dim), lambda i, uids_ref, off_ref: (i, 0))
-    scalar_by_slot = pl.BlockSpec((1,), lambda i, uids_ref, off_ref: (i,))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(cap,),
-        in_specs=[row_by_uid, row_by_uid, row_by_uid,
-                  scalar_by_slot, scalar_by_slot],
-        out_specs=[row_by_slot, row_by_slot, row_by_slot],
-    )
     del b1, b2, eps
-    kernel = functools.partial(_catchup_kernel, factor=decay_factor(lr, l2))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((cap, dim), jnp.float32)] * 3,
+    loc = uids - row_offset
+    cap, dim = uids.shape[0], w.shape[1]
+    block_rows = min(block_rows or default_block_rows(dim), cap)
+    rows = pl.BlockSpec((block_rows, dim), lambda i: (i, 0))
+    col = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
+    lim = jnp.reshape(step - 1, (1, 1)).astype(jnp.int32)
+
+    w_rows = pl.pallas_call(
+        functools.partial(_catchup_kernel, factor=decay_factor(lr, l2)),
+        grid=(pl.cdiv(cap, block_rows),),
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), rows, col],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((cap, dim), jnp.float32),
         interpret=interpret,
-    )(uids, off, w, m, v, ls_rows, lim)
-
-
-# ---------------------------------------------------------------------------
-# kernel B: CowClip + L2 + Adam + scatter (in-place on the tables)
-# ---------------------------------------------------------------------------
-
-
-def _update_kernel(uids_ref, off_ref, bc_ref, w_tab_ref, m_tab_ref, v_tab_ref,
-                   wr_ref, gr_ref, cnt_ref, mr_ref, vr_ref,
-                   w_out, m_out, v_out,
-                   *, r, zeta, lr, l2, b1, b2, eps, do_clip):
-    del uids_ref, off_ref, w_tab_ref, m_tab_ref, v_tab_ref  # index-map only
-    cnt = cnt_ref[0]
-
-    @pl.when(cnt > 0.0)                            # pad slots write nothing
-    def _():
-        w = wr_ref[...].astype(jnp.float32)        # (1, dim), caught-up row
-        g = gr_ref[...].astype(jnp.float32)
-        m = mr_ref[...].astype(jnp.float32)
-        v = vr_ref[...].astype(jnp.float32)
-        bc1 = bc_ref[0, 0]                         # 1/(1-b1^t)
-        bc2 = bc_ref[0, 1]                         # 1/(1-b2^t)
-
-        if do_clip:
-            gnorm = jnp.sqrt(jnp.sum(g * g))
-            wnorm = jnp.sqrt(jnp.sum(w * w))
-            clip_t = cnt * jnp.maximum(r * wnorm, zeta)
-            g = g * jnp.minimum(1.0, clip_t / (gnorm + 1e-30))
-
-        g = g + l2 * w
-        m = b1 * m + (1.0 - b1) * g
-        v = b2 * v + (1.0 - b2) * g * g
-        w = w - lr * (m * bc1) / (jnp.sqrt(v * bc2) + eps)
-
-        w_out[...] = w.astype(w_out.dtype)
-        m_out[...] = m.astype(m_out.dtype)
-        v_out[...] = v.astype(v_out.dtype)
+    )(lim, w[loc], last_step[loc].astype(jnp.int32)[:, None])
+    return w_rows, m[loc].astype(jnp.float32), v[loc].astype(jnp.float32)
 
 
 def sparse_update_scatter(
-    w: jnp.ndarray,           # [rows, dim] table or shard (donated, in place)
-    m: jnp.ndarray,           # [rows, dim] Adam first moment (donated)
-    v: jnp.ndarray,           # [rows, dim] Adam second moment (donated)
-    uids: jnp.ndarray,        # [cap] int32 in-range slot uids (safe_uids)
+    w: jnp.ndarray,           # [rows, dim] table or shard
+    m: jnp.ndarray,           # [rows, dim] Adam first moment
+    v: jnp.ndarray,           # [rows, dim] Adam second moment
+    uids: jnp.ndarray,        # [cap] int32 slot uids
     counts: jnp.ndarray,      # [cap] f32 per-slot batch counts (0 on pads)
     w_rows: jnp.ndarray,      # [cap, dim] caught-up rows (f32)
     g_rows: jnp.ndarray,      # [cap, dim] task-loss gradient on rows
@@ -197,47 +125,19 @@ def sparse_update_scatter(
     eps: float = 1e-8,
     clip: bool = True,
     row_offset=0,             # subtracted from uids: shard's first global row
+    block_rows: int = 0,
     interpret: bool = False,
 ):
-    """Fused CowClip+L2+Adam over unique rows, scattered into the tables
-    through aliased outputs. Returns updated (w, m, v) full tables; rows of
-    ids absent from the batch are not touched (their decay stays pending)."""
-    cap = uids.shape[0]
-    dim = w.shape[1]
-    t = step.astype(jnp.float32)
-    bc = jnp.stack([1.0 / (1.0 - b1**t), 1.0 / (1.0 - b2**t)]).reshape(1, 2)
-    off = jnp.full((1,), row_offset, jnp.int32)
-
-    row_by_uid = pl.BlockSpec(
-        (1, dim), lambda i, uids_ref, off_ref: (uids_ref[i] - off_ref[0], 0))
-    row_by_slot = pl.BlockSpec((1, dim), lambda i, uids_ref, off_ref: (i, 0))
-    scalar_by_slot = pl.BlockSpec((1,), lambda i, uids_ref, off_ref: (i,))
-    bc_block = pl.BlockSpec((1, 2), lambda i, uids_ref, off_ref: (0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(cap,),
-        in_specs=[bc_block, row_by_uid, row_by_uid, row_by_uid,
-                  row_by_slot, row_by_slot, scalar_by_slot,
-                  row_by_slot, row_by_slot],
-        out_specs=[row_by_uid, row_by_uid, row_by_uid],
-    )
-    kernel = functools.partial(
-        _update_kernel, r=r, zeta=zeta, lr=lr, l2=l2, b1=b1, b2=b2, eps=eps,
-        # paper appendix: 1-dim LR-stream tables are CowClip-exempt
-        do_clip=clip and dim >= 2,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(w.shape, w.dtype),
-            jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        # (w, m, v) table inputs alias the three outputs: untouched rows are
-        # never DMA'd, so the update writes only O(n_unique) HBM traffic.
-        # Operand order: (uids, off, bc, w, m, v, ...) -> w/m/v at 3/4/5.
-        input_output_aliases={3: 0, 4: 1, 5: 2},
-        interpret=interpret,
-    )(uids, off, bc, w, m, v, w_rows, g_rows, counts, m_rows, v_rows)
+    """Fused CowClip+L2+Adam on the slot rows, scattered into the tables.
+    Returns updated (w, m, v) full tables; rows of ids absent from the
+    batch are not touched (their decay stays pending)."""
+    w_new, m_new, v_new = cowclip_adam_update(
+        w_rows, g_rows, counts, m_rows, v_rows, step, r=r, zeta=zeta, lr=lr,
+        l2=l2, b1=b1, b2=b2, eps=eps, clip=clip, block_rows=block_rows,
+        interpret=interpret)
+    # pad slots (count 0) are forced out of range: with a row_offset the
+    # raw pad uid (vocab) minus the offset could otherwise land in range
+    loc = jnp.where(counts > 0, uids - row_offset, w.shape[0])
+    return (w.at[loc].set(w_new.astype(w.dtype), mode="drop"),
+            m.at[loc].set(m_new.astype(m.dtype), mode="drop"),
+            v.at[loc].set(v_new.astype(v.dtype), mode="drop"))

@@ -1,4 +1,7 @@
-"""jit'd public wrapper for the chunked WKV6 scan (interpret off-TPU)."""
+"""jit'd public wrapper for the chunked WKV6 scan.
+
+Runs the jnp oracle unless the caller asks for the kernel; a kernel asked
+for compiles on a TPU and runs in interpret mode elsewhere."""
 
 from __future__ import annotations
 
@@ -6,16 +9,14 @@ from functools import partial
 
 import jax
 
+from .. import interpret_off_tpu
 from .ref import wkv6_reference as reference
 from .wkv6 import chunked_wkv6
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("chunk", "use_kernel"))
-def wkv6(r, k, v, w, u, *, chunk=16, use_kernel=True):
+def wkv6(r, k, v, w, u, *, chunk=16, use_kernel=False):
     if not use_kernel:
         return reference(r, k, v, w, u)
-    return chunked_wkv6(r, k, v, w, u, chunk=chunk, interpret=not _on_tpu())
+    return chunked_wkv6(r, k, v, w, u, chunk=chunk,
+                        interpret=interpret_off_tpu())

@@ -31,11 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names this TPUCompilerParams; newer releases CompilerParams
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 CLAMP = 25.0
 
 
@@ -53,35 +48,46 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, sfin_ref, state,
     w = w_ref[0].astype(jnp.float32)          # decay in (0, 1)
     u = u_ref[0].astype(jnp.float32)          # [1, N] bonus
 
-    logw = jnp.log(jnp.maximum(w, 1e-38))
-    cum = jnp.cumsum(logw, axis=0)            # inclusive  [L, N]
-    cum_prev = cum - logw                     # exclusive
-    cref = 0.5 * cum[-1]                      # [N] mid-chunk reference
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
 
-    r_hat = r * jnp.exp(jnp.clip(cum_prev - cref[None, :], -CLAMP, CLAMP))
-    k_hat = k * jnp.exp(jnp.clip(cref[None, :] - cum, -CLAMP, CLAMP))
+    logw = jnp.log(jnp.maximum(w, 1e-38))
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum); HIGHEST keeps it at f32 accuracy on the MXU
+    cum = jax.lax.dot(
+        (t_idx >= j_idx).astype(jnp.float32), logw,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )                                          # [L, N]
+    cum_prev = cum - logw                     # exclusive
+    # static 2-D slices throughout: Mosaic lowers no dynamic_slice, and a
+    # negative index (cum[-1]) becomes one
+    cum_last = cum[chunk - 1:chunk, :]        # [1, N]
+    cref = 0.5 * cum_last                     # mid-chunk reference
+
+    r_hat = r * jnp.exp(
+        jnp.minimum(jnp.maximum(cum_prev - cref, -CLAMP), CLAMP))
+    k_hat = k * jnp.exp(jnp.minimum(jnp.maximum(cref - cum, -CLAMP), CLAMP))
 
     # intra-chunk, strictly causal (j < t)
     a = jax.lax.dot_general(
         r_hat, k_hat, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                          # [L, L]
-    t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     a = jnp.where(t_idx > j_idx, a, 0.0)
 
-    bonus = jnp.sum(r * u * k, axis=-1)        # [L] diagonal u-term
+    bonus = jnp.sum(r * u * k, axis=-1, keepdims=True)   # [L, 1] u-term
 
     s0 = state[...]                            # [N, N]
     y = (
         a @ v
         + (r * jnp.exp(cum_prev)) @ s0
-        + bonus[:, None] * v
+        + bonus * v
     )
 
     # inter-chunk state carry: exponents <= 0, always safe
-    k_tail = k * jnp.exp(cum[-1][None, :] - cum)
-    state[...] = jnp.exp(cum[-1])[:, None] * s0 + jax.lax.dot_general(
+    k_tail = k * jnp.exp(cum_last - cum)
+    state[...] = jnp.exp(cum_last).T * s0 + jax.lax.dot_general(
         k_tail, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -107,7 +113,9 @@ def chunked_wkv6(
     n_chunks = s // chunk
 
     seq_block = pl.BlockSpec((1, chunk, n), lambda b, c: (b, c, 0))
-    u_block = pl.BlockSpec((1, n), lambda b, c: (b, 0))
+    # u as [bh, 1, n]: a (1, n) block of a [bh, n] array breaks the TPU's
+    # rule that a block's last two dims divide by (8, 128) or span the array
+    u_block = pl.BlockSpec((1, 1, n), lambda b, c: (b, 0, 0))
     sfin_block = pl.BlockSpec((1, n, n), lambda b, c: (b, 0, 0))
 
     y, sfin = pl.pallas_call(
@@ -120,9 +128,9 @@ def chunked_wkv6(
             jax.ShapeDtypeStruct((bh, n, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(r, k, v, w, u)
+    )(r, k, v, w, u[:, None, :])
     return y, sfin

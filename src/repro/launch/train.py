@@ -14,6 +14,9 @@ Two families:
 Usage:
   PYTHONPATH=src python -m repro.launch.train --task ctr --model deepfm \
       --batch 8192 --epochs 2 --rule cowclip
+  # the paper's DeepFM at Criteo widths (26 fields, ~33.8M ids) on one chip:
+  PYTHONPATH=src python -m repro.launch.train --task ctr --arch deepfm-criteo \
+      --placement sparse --batch 8192 --samples 262144 --steps 16
   # mesh-sharded embeddings on 8 virtual CPU devices (2-way data, 4-way row):
   PYTHONPATH=src python -m repro.launch.train --task ctr --placement sharded \
       --mesh 2,4 --host-devices 8 --batch 8192 --epochs 1
@@ -30,7 +33,10 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +52,25 @@ from .mesh import make_ctr_mesh, parse_mesh
 
 
 MESH_PLACEMENTS = ("sharded", "sharded_sparse")
+
+# the five synthetic fields CTR runs use when --arch names no CTR config
+DEFAULT_VOCABS = (30000, 80000, 5000, 1000, 200)
+
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    stands. Otherwise the cache lives in ``<checkout>/.jax_cache``: a fixed
+    path, since the path is part of what a later run must find again.
+    """
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return os.environ["JAX_COMPILATION_CACHE_DIR"]
+    path = str(CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def resolve_placement(placement, sparse_flag, *,
@@ -68,16 +93,67 @@ def resolve_placement(placement, sparse_flag, *,
     return placement
 
 
-def run_ctr(args) -> None:
+def ctr_arch(arch: str) -> Optional[ctr_lib.CTRConfig]:
+    """The registered CTR config ``--arch`` names, or None for an LM arch
+    (CTR runs then use the five synthetic ``DEFAULT_VOCABS`` fields)."""
+    cfg = get_config(arch)
+    return cfg if isinstance(cfg, ctr_lib.CTRConfig) else None
+
+
+def make_ctr_data(args):
+    """The run's dataset: ``--criteo`` TSV rows, else the seeded synthetic
+    generator at the ``--arch`` config's vocabs (or the default fields)."""
+    if args.criteo:
+        return load_criteo_tsv(args.criteo, max_rows=args.max_rows)
+    arch = ctr_arch(args.arch)
+    if arch is not None:
+        vocabs, n_dense = arch.vocab_sizes, arch.n_dense
+    else:
+        vocabs = tuple(v * args.vocab_scale for v in DEFAULT_VOCABS)
+        n_dense = 4
+    return make_ctr_dataset(args.samples, vocabs, n_dense=n_dense,
+                            zipf_a=1.1, seed=args.seed)
+
+
+def make_ctr_config(args, ds, placement) -> ctr_lib.CTRConfig:
+    """Model widths from the ``--arch`` CTR config when it names one, else
+    from ``--emb-dim``/``--mlp-dim``; vocabs from the data."""
+    arch = ctr_arch(args.arch)
+    return ctr_lib.CTRConfig(
+        name=args.model, vocab_sizes=ds.vocab_sizes,
+        n_dense=ds.dense.shape[1],
+        emb_dim=arch.emb_dim if arch else args.emb_dim,
+        mlp_dims=arch.mlp_dims if arch else (args.mlp_dim,) * 3,
+        emb_sigma=1e-2, sparse=placement == "sparse",
+        unique_capacity=args.unique_capacity, placement=placement,
+        compute_dtype=args.compute_dtype,
+    )
+
+
+def make_ctr_bundle(args, cfg, store, n_train: int, *,
+                    use_kernel: bool = False):
+    """The run's train-step bundle: the ``--rule`` scaled hyperparameters
+    and the CowClip clip (rule cowclip) through ``store``'s placement."""
+    hp = scale_hyperparams(
+        args.rule, base_lr=args.base_lr, base_l2=args.base_l2,
+        base_batch=args.base_batch, batch_size=args.batch,
+        base_dense_lr=2 * args.base_lr,
+    )
+    clip = "adaptive_column" if args.rule == "cowclip" else "none"
+    return store.make_bundle(cfg, hp, clip_kind=clip, zeta=args.zeta,
+                             warmup_steps=max(1, n_train // args.batch),
+                             nonfinite_guard=args.nonfinite_guard,
+                             use_kernel=use_kernel)
+
+
+def run_ctr(args, data=None):
+    """Train a CTR model as ``args`` (``parse_args``) says and return the
+    ``train.loop.TrainResult``. ``data`` is a ``CTRDataset`` to train on in
+    place of ``make_ctr_data(args)`` (one generated set shared by several
+    in-process runs)."""
     from ..embed import store_for
 
-    if args.criteo:
-        ds = load_criteo_tsv(args.criteo, max_rows=args.max_rows)
-    else:
-        vocabs = tuple(v * args.vocab_scale
-                       for v in (30000, 80000, 5000, 1000, 200))
-        ds = make_ctr_dataset(args.samples, vocabs, n_dense=4, zipf_a=1.1,
-                              seed=args.seed)
+    ds = data if data is not None else make_ctr_data(args)
     tr, te = ds.split(0.9)
     placement = resolve_placement(args.placement, args.sparse)
     if args.mode == "stream" and args.steps is None:
@@ -105,13 +181,7 @@ def run_ctr(args) -> None:
     elif args.resume:
         raise SystemExit("[train] --resume needs --snapshot-dir (where the "
                          "snapshots live)")
-    cfg = ctr_lib.CTRConfig(
-        name=args.model, vocab_sizes=ds.vocab_sizes,
-        n_dense=ds.dense.shape[1], emb_dim=args.emb_dim,
-        mlp_dims=(args.mlp_dim,) * 3, emb_sigma=1e-2,
-        sparse=placement == "sparse", unique_capacity=args.unique_capacity,
-        placement=placement, compute_dtype=args.compute_dtype,
-    )
+    cfg = make_ctr_config(args, ds, placement)
     mesh = None
     if placement in MESH_PLACEMENTS:
         mesh = make_ctr_mesh(*(parse_mesh(args.mesh) if args.mesh else (0, 0)))
@@ -132,17 +202,8 @@ def run_ctr(args) -> None:
           f"embedding store {store.describe()}, engine {engine_desc}, "
           f"mode {mode_desc}, compute {args.compute_dtype})")
 
-    hp = scale_hyperparams(
-        args.rule, base_lr=args.base_lr, base_l2=args.base_l2,
-        base_batch=args.base_batch, batch_size=args.batch,
-        base_dense_lr=2 * args.base_lr,
-    )
-    clip = "adaptive_column" if args.rule == "cowclip" else "none"
-    warmup = max(1, len(tr) // args.batch)
     # every placement goes through the one EmbeddingStore bundle interface
-    bundle = store.make_bundle(cfg, hp, clip_kind=clip, zeta=args.zeta,
-                               warmup_steps=warmup,
-                               nonfinite_guard=args.nonfinite_guard)
+    bundle = make_ctr_bundle(args, cfg, store, len(tr))
     import contextlib
 
     trace_ctx = contextlib.nullcontext()
@@ -314,6 +375,7 @@ def run_ctr(args) -> None:
         })
         print(f"[train] final params checkpointed to {args.checkpoint} "
               "(with id_freq for serving)")
+    return res
 
 
 def run_lm(args) -> None:
@@ -380,7 +442,8 @@ def run_lm(args) -> None:
     assert losses[-1] < losses[0], "training did not reduce loss"
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The CLI's options (``argv=None`` reads ``sys.argv``)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--task", choices=("ctr", "lm"), default="ctr")
     # ctr
@@ -489,7 +552,12 @@ def main():
                          "thing in main)")
     ap.add_argument("--epochs", type=int, default=10)
     # lm
-    ap.add_argument("--arch", default="gemma3-12b")
+    ap.add_argument("--arch", default="gemma3-12b",
+                    help="registered config (repro.configs): an LM arch for "
+                         "--task lm; with --task ctr a CTR config such as "
+                         "deepfm-criteo sets the vocabs, dense features, "
+                         "embedding dim and MLP widths (an LM arch keeps the "
+                         "five synthetic fields)")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--steps", type=int, default=None,
@@ -502,8 +570,11 @@ def main():
     ap.add_argument("--profile-trace", default=None, metavar="DIR",
                     help="ctr: dump a jax.profiler trace (with a perfetto "
                          "trace file) of the training run to DIR")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def main():
+    args = parse_args()
     if args.host_devices:
         # must land before the first jax backend touch (nothing above this
         # point creates arrays or queries devices — imports alone don't)
@@ -515,6 +586,7 @@ def main():
                 f"device_count={args.host_devices} in the environment "
                 "instead")
 
+    use_compile_cache()
     if args.task == "ctr":
         run_ctr(args)
     else:

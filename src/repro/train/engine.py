@@ -171,12 +171,14 @@ def run_epoch(
     shuffle: bool = True,
     max_steps: Optional[int] = None,
     buffer_size: int = 2,
+    on_chunk: Optional[Callable[[int, dict], None]] = None,
 ) -> Tuple[object, object, int, Optional[dict]]:
     """One epoch of scan-fused chunks through ``runner``.
 
     Returns ``(params, opt_state, steps_run, last_aux_stack)``. Respects
     ``max_steps`` (remaining budget for *this* epoch) by slicing the final
     chunk's leading axis — at most one extra compile for the cut shape.
+    ``on_chunk(k, aux_stack)`` is called after each dispatch.
     """
     steps_run = 0
     last_aux = None
@@ -191,6 +193,8 @@ def run_epoch(
                 break
             chunk = jax.tree.map(lambda x: x[:k], chunk)
         params, opt_state, last_aux = runner(params, opt_state, chunk)
+        if on_chunk is not None:
+            on_chunk(k, last_aux)
         steps_run += k
         if max_steps is not None and steps_run >= max_steps:
             break

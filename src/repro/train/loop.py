@@ -79,12 +79,13 @@ def _uniq_tree(embed_params: dict, uniq: dict) -> dict:
 
 def make_fused_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
                           zeta: float = 1e-5, dense_tx=None,
-                          use_kernel: bool = True):
-    """Train step that runs every embedding table through the fused Pallas
-    CowClip+L2+Adam kernel (repro.kernels.cowclip) instead of the composable
-    transform chain — the TPU fast path. Dense tower still goes through the
-    substrate optimizer. State: {"step", "m", "v"} trees for embeddings +
-    the dense transform state.
+                          use_kernel: bool = False):
+    """Train step that runs every embedding table through one fused
+    CowClip+L2+Adam update per table (repro.kernels.cowclip) instead of the
+    composable transform chain: its jnp form by default, the Pallas kernel
+    with ``use_kernel=True``. Dense tower still goes through the substrate
+    optimizer. State: {"step", "m", "v"} trees for embeddings + the dense
+    transform state.
 
     With ``cfg.sparse`` this routes to ``make_sparse_train_step`` (the
     unique-id gather -> fused-update -> scatter path) and returns its full
@@ -147,14 +148,15 @@ def make_fused_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
 
 def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
                            zeta: float = 1e-5, dense_tx=None,
-                           use_kernel: bool = True, clip: bool = True,
+                           use_kernel: bool = False, clip: bool = True,
                            b1: float = 0.9, b2: float = 0.999,
                            eps: float = 1e-8):
     """The sparse unique-id train step: per step, each field's batch ids are
     deduplicated once and the embedding update runs entirely on the
     ``[n_unique, dim]`` gathered rows — gather -> lazy-L2-decay catch-up ->
     forward/backward on rows -> CowClip -> Adam -> scatter. Update HBM
-    traffic is O(batch), not O(vocab).
+    traffic is O(batch), not O(vocab). The row math is jnp by default, the
+    Pallas row kernels (repro.kernels.cowclip.sparse) with ``use_kernel``.
 
     Ids absent from a batch are not touched; their coupled-L2 decay accrues
     in a per-row ``last_step`` array and is replayed on next touch (or by
@@ -205,8 +207,7 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
         with jax.named_scope("row_gather_catchup"):
             caught = jax.tree.map(
                 lambda u, w, m, v, ls: cc_kernels.sparse_gather_catchup(
-                    w, m, v, ls, u.uids, u.counts, t,
-                    use_kernel=use_kernel, **adam_kw),
+                    w, m, v, ls, u.uids, t, use_kernel=use_kernel, **adam_kw),
                 utree, params["embed"], state["m"], state["v"],
                 state["last_step"], is_leaf=_is_uniq,
             )
@@ -252,23 +253,27 @@ def _make_lazy_flush(adam_kw: dict):
     """The flush shared by every lazy-decay placement (sparse and
     sharded_sparse): apply each row's pending decay-only steps through the
     current step, then stamp ``last_step = step`` everywhere. Idempotent —
-    a second call replays zero iterations and rewrites identical values."""
+    a second call replays zero iterations and rewrites identical values.
+
+    The Adam moments pass through as they are (decay-only steps never move
+    them), outside the jitted settle: as its outputs they would be fresh
+    copies of both moment tables, doubling the state on the device."""
     from ..core import optim as optim_lib
 
     @jax.jit
-    def flush(params, state):
+    def settle(embed, m, v, last_step, step):
         caught = jax.tree.map(
-            lambda w, m, v, ls: optim_lib.decay_catchup_rows(
-                w, m, v, ls, state["step"], **adam_kw),
-            params["embed"], state["m"], state["v"], state["last_step"],
-        )
-        new_embed, new_m, new_v = _unzip3(caught, params["embed"])
-        new_embed = jax.tree.map(
-            lambda w, p: w.astype(p.dtype), new_embed, params["embed"])
-        new_ls = jax.tree.map(
-            lambda ls: jnp.full_like(ls, state["step"]), state["last_step"])
-        new_state = dict(state, m=new_m, v=new_v, last_step=new_ls)
-        return dict(params, embed=new_embed), new_state
+            lambda w, m_, v_, ls: optim_lib.decay_catchup_rows(
+                w, m_, v_, ls, step, **adam_kw)[0].astype(w.dtype),
+            embed, m, v, last_step)
+        return caught, jax.tree.map(lambda ls: jnp.full_like(ls, step),
+                                    last_step)
+
+    def flush(params, state):
+        new_embed, new_ls = settle(params["embed"], state["m"], state["v"],
+                                   state["last_step"], state["step"])
+        return (dict(params, embed=new_embed),
+                dict(state, last_step=new_ls))
 
     return flush
 
@@ -378,11 +383,16 @@ def make_sharded_train_step(cfg: ctr.CTRConfig, hp, mesh, *,
                             m_sh[group][f], v_sh[group][f], t, **upd_kw))
         return new_w, new_m, new_v, g_dense, loss
 
+    # check_vma=False: the collectives are written out (every grad is
+    # psum'd over "data" by hand). With jax's replication checker on, AD
+    # would also sum the grads of the "data"-replicated inputs over "data"
+    # itself, and the hand-written psum would count them n_data times.
     smapped = shard_lib.shard_map(
         local_step, mesh=mesh,
         in_specs=(EMB, EMB, EMB, REP, REP,
                   P("data", None), P("data", None), P("data")),
         out_specs=(EMB, EMB, EMB, REP, REP),
+        check_vma=False,
     )
 
     def step_impl(params, state, batch):
@@ -480,7 +490,6 @@ def make_sharded_sparse_train_step(cfg: ctr.CTRConfig, hp, mesh, *,
     adam_kw = dict(lr=hp.emb_lr, l2=hp.emb_l2, b1=b1, b2=b2, eps=eps)
     upd_kw = dict(clip=clip, r=r, zeta=zeta, **adam_kw)
     factor = optim_lib.decay_factor(hp.emb_lr, hp.emb_l2)
-    interpret = jax.default_backend() != "tpu"
     n_fields = cfg.n_fields
 
     EMB = P("model", None)   # prefix spec: broadcasts over the embed tree
@@ -634,19 +643,20 @@ def make_sharded_sparse_train_step(cfg: ctr.CTRConfig, hp, mesh, *,
                         embed_sh[group][f], m_sh[group][f], v_sh[group][f],
                         ls_sh[group][f], uloc, cnts, ovf,
                         g_slots, g_full, cnt_full[f], t,
-                        use_kernel=use_kernel, interpret=interpret, **upd_kw)
+                        use_kernel=use_kernel, **upd_kw)
         return new_w, new_m, new_v, new_ls, g_dense, loss, n_overflow, depth
 
-    # check_rep=False: the lazy-decay catch-up is a while loop (traced trip
-    # count) inside lax.cond, for which jax 0.4.x's shard_map replication
-    # checker has no rule; the collectives here are the same psums as the
-    # dense sharded step, just outside the conds.
+    # check_vma=False: each model shard updates its rows from the dedup of
+    # the "data"-all-gathered ids and the psum'd row grads, so the new
+    # tables (and the depth diagnostic) are identical on every "data"
+    # slice, but jax's replication checker cannot follow that through the
+    # sort-based dedup and would reject the replicated out_specs.
     smapped = shard_lib.shard_map(
         local_step, mesh=mesh,
         in_specs=(EMB, EMB, EMB, LS, REP, REP,
                   P("data", None), P("data", None), P("data")),
         out_specs=(EMB, EMB, EMB, LS, REP, REP, REP, REP),
-        check_rep=False,
+        check_vma=False,
     )
 
     def step_impl(params, state, batch):
@@ -736,6 +746,12 @@ class TrainResult:
     # and for asserting bundle contracts (e.g. flush idempotence) in tests
     params: object = None
     opt_state: object = None
+    # epochs mode only: the training loss of every step, the seconds spent
+    # in train steps with the device synced (eval and flush excluded), and
+    # (steps, seconds) of the first dispatch, which carries the compile
+    losses: list = dataclasses.field(default_factory=list)
+    train_seconds: float = 0.0
+    first_chunk: tuple = (0, 0.0)
 
 
 def train_ctr(
@@ -900,27 +916,42 @@ def train_ctr(
                            seconds=seconds, steps=n_steps, params=params,
                            opt_state=opt_state)
 
+    losses = []           # per-dispatch losses ([k] or scalar), fetched at the end
+    train_seconds = 0.0
+    first_chunk = None
     for epoch in range(epochs):
         if max_steps is not None and n_steps >= max_steps:
             break
+        t_epoch = time.perf_counter()
+
+        def on_chunk(k, aux):
+            nonlocal first_chunk
+            losses.append(aux["loss"])
+            if first_chunk is None:
+                jax.block_until_ready(aux)
+                first_chunk = (k, time.perf_counter() - t_epoch)
+
         if engine == "scan":
             params, opt_state, ran, _ = engine_lib.run_epoch(
                 runner, params, opt_state, train_ds, batch_size, scan_steps,
                 seed=seed + epoch,
                 max_steps=(None if max_steps is None
                            else max_steps - n_steps),
-                buffer_size=prefetch_buffers)
+                buffer_size=prefetch_buffers, on_chunk=on_chunk)
             n_steps += ran
         else:
             for b in iterate_batches(train_ds, batch_size, seed=seed + epoch):
                 batch = {k: jnp.asarray(v) for k, v in b.items()}
                 params, opt_state, aux = step_fn(params, opt_state, batch)
+                on_chunk(1, aux)
                 n_steps += 1
                 if snapshot_cb is not None:
                     params, opt_state = snapshot_cb(params, opt_state,
                                                     n_steps)
                 if max_steps is not None and n_steps >= max_steps:
                     break
+        jax.block_until_ready((params, opt_state))
+        train_seconds += time.perf_counter() - t_epoch
         if eval_every_epoch and test_ds is not None:
             if flush is not None:
                 params, opt_state = flush(params, opt_state)
@@ -938,5 +969,10 @@ def train_ctr(
         if history
         else (eval_fn(params, test_ds) if test_ds is not None else {})
     )
-    return TrainResult(history=history, final_eval=dict(final), seconds=seconds,
-                       steps=n_steps, params=params, opt_state=opt_state)
+    return TrainResult(
+        history=history, final_eval=dict(final), seconds=seconds,
+        steps=n_steps, params=params, opt_state=opt_state,
+        losses=[float(x) for x in np.concatenate(
+            [np.atleast_1d(x) for x in jax.device_get(losses)])]
+        if losses else [],
+        train_seconds=train_seconds, first_chunk=first_chunk or (0, 0.0))

@@ -177,16 +177,14 @@ def test_kernel_catchup_matches_replay_with_row_offset(depth, row_offset,
     m = jnp.asarray(rng.normal(size=(rows, dim)).astype(np.float32))
     v = jnp.asarray(np.abs(rng.normal(size=(rows, dim))).astype(np.float32))
     ls = jnp.asarray(rng.integers(0, depth, size=rows).astype(np.int32))
-    # distinct owned ids, global (shard-offset) numbering; one pad slot
-    local = rng.choice(rows, size=cap - 1, replace=False).astype(np.int32)
+    # distinct owned ids, global (shard-offset) numbering
+    local = rng.choice(rows, size=cap, replace=False).astype(np.int32)
     uids = jnp.asarray(np.sort(local) + row_offset)
-    # pad slot: safe_uids convention duplicates the last real uid
-    uids = jnp.concatenate([uids, jnp.asarray([uids[-1]], jnp.int32)])
     step = jnp.asarray(depth, jnp.int32)
 
     w_k, m_k, v_k = cc_sparse.sparse_gather_catchup(
-        w, m, v, ls[uids - row_offset], uids, step, lr=lr, l2=l2,
-        row_offset=row_offset, interpret=True)
+        w, m, v, ls, uids, step, lr=lr, l2=l2, row_offset=row_offset,
+        interpret=True)
 
     loc = np.asarray(uids) - row_offset
     w_rp = optim_lib.decay_replay_reference(w[loc], ls[loc], step - 1,
@@ -234,7 +232,7 @@ def test_first_touch_at_step_10000_matches_dense_run():
     # sparse placement: one closed-form catch-up at first touch
     uids = jnp.arange(vocab, dtype=jnp.int32)[:8]
     w_rows, m_rows, v_rows = cc_sparse.sparse_gather_catchup(
-        w, m, v, ls[uids], uids, t, lr=lr, l2=l2, interpret=True)
+        w, m, v, ls, uids, t, lr=lr, l2=l2, interpret=True)
 
     np.testing.assert_allclose(np.asarray(w_rows), np.asarray(w_dense)[:8],
                                atol=1e-5, rtol=0)

@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps asserting allclose against the
-pure-jnp ref.py oracles (kernels run in interpret mode on CPU)."""
+pure-jnp ref.py oracles (``use_kernel=True``: kernels run in interpret
+mode on CPU)."""
 
 try:
     import hypothesis
@@ -40,7 +41,7 @@ def test_cowclip_kernel_shape_sweep(vocab, dim, dtype):
     w, g, cnt, m, v = _cowclip_inputs(vocab, dim, dtype, seed=vocab + dim)
     step = jnp.asarray(3, jnp.int32)
     kw = dict(r=1.0, zeta=1e-5, lr=1e-4, l2=1e-5)
-    out_k = fused_cowclip_adam(w, g, cnt, m, v, step, **kw)
+    out_k = fused_cowclip_adam(w, g, cnt, m, v, step, use_kernel=True, **kw)
     out_r = cowclip_ref(w, g, cnt, m, v, step, **kw)
     for a, b, name in zip(out_k, out_r, ("w", "m", "v")):
         np.testing.assert_allclose(
@@ -53,7 +54,8 @@ def test_cowclip_kernel_block_shape_invariance(block_rows):
     w, g, cnt, m, v = _cowclip_inputs(1000, 16, jnp.float32)
     step = jnp.asarray(11, jnp.int32)
     base = cowclip_ref(w, g, cnt, m, v, step)
-    out = fused_cowclip_adam(w, g, cnt, m, v, step, block_rows=block_rows)
+    out = fused_cowclip_adam(w, g, cnt, m, v, step, block_rows=block_rows,
+                             use_kernel=True)
     for a, b in zip(out, base):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-7)
@@ -70,7 +72,7 @@ def test_cowclip_kernel_hyperparam_property(step, r, zeta, seed):
     w, g, cnt, m, v = _cowclip_inputs(128, 8, jnp.float32, seed=seed)
     s = jnp.asarray(step, jnp.int32)
     kw = dict(r=r, zeta=zeta, lr=1e-3, l2=1e-4)
-    out_k = fused_cowclip_adam(w, g, cnt, m, v, s, **kw)
+    out_k = fused_cowclip_adam(w, g, cnt, m, v, s, use_kernel=True, **kw)
     out_r = cowclip_ref(w, g, cnt, m, v, s, **kw)
     for a, b in zip(out_k, out_r):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
@@ -99,7 +101,7 @@ def _wkv_inputs(bh, s, n, seed=0, wlog_std=1.0):
 ])
 def test_wkv6_kernel_shape_sweep(bh, s, n):
     inp = _wkv_inputs(bh, s, n, seed=bh * s + n)
-    yk, sk = wkv6(*inp)
+    yk, sk = wkv6(*inp, use_kernel=True)
     yr, sr = wkv_ref(*inp)
     scale = float(jnp.max(jnp.abs(yr))) + 1e-6
     assert float(jnp.max(jnp.abs(yk - yr))) / scale < 1e-4
@@ -111,7 +113,7 @@ def test_wkv6_kernel_shape_sweep(bh, s, n):
 def test_wkv6_chunk_invariance(chunk):
     inp = _wkv_inputs(2, 64, 16, seed=7)
     yr, sr = wkv_ref(*inp)
-    yk, sk = wkv6(*inp, chunk=chunk)
+    yk, sk = wkv6(*inp, chunk=chunk, use_kernel=True)
     scale = float(jnp.max(jnp.abs(yr))) + 1e-6
     assert float(jnp.max(jnp.abs(yk - yr))) / scale < 1e-4
 
@@ -119,7 +121,7 @@ def test_wkv6_chunk_invariance(chunk):
 def test_wkv6_rejects_ragged_seq():
     inp = _wkv_inputs(1, 40, 8)
     with pytest.raises(ValueError):
-        wkv6(*inp, chunk=16)
+        wkv6(*inp, chunk=16, use_kernel=True)
 
 
 def test_wkv6_matches_model_mixer():
@@ -142,7 +144,7 @@ def test_wkv6_matches_model_mixer():
                 .reshape(bsz * n_heads, seq, n))
     u = jnp.broadcast_to(params["u"].reshape(n_heads, n),
                          (bsz, n_heads, n)).reshape(bsz * n_heads, n)
-    yk, _ = wkv6(heads(r), heads(k), heads(v), heads(w), u)
+    yk, _ = wkv6(heads(r), heads(k), heads(v), heads(w), u, use_kernel=True)
     yk = yk.reshape(bsz, n_heads, seq, n).transpose(0, 2, 1, 3)  # [B,S,H,N]
     yk = rwkv._head_norm(params, yk)
     # full-module comparison: apply gate + wo to the kernel output

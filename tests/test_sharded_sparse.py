@@ -2,7 +2,8 @@
 single-device (1x1 mesh) equivalence with lazy decay, the capacity-overflow
 dense fallback (including mid-run overflow), shard-offset-aware kernels vs
 their oracles, store/CLI routing — and the full multi-device exactness
-matrix (2x4 / 8x1 / mod / overflow) in an 8-virtual-device subprocess.
+matrix (2x4 / 8x1 / mod / overflow) in an 8-virtual-device subprocess, and
+a float64 run on a 2x2 mesh of four against the sparse placement.
 
 The contract under test: the hybrid step — per-shard dedup of the global
 batch, gather + lazy-L2-decay catch-up via per-row ``last_step``, fused
@@ -290,10 +291,9 @@ def test_sparse_kernels_row_offset_match_oracle(dim):
     # oracle with pre-localized ids agrees (pads vocab-off=35 out of range)
     loc_rows = cc_ref.sparse_gather_catchup_reference(
         w_sh, m_sh, v_sh, ls_sh, uids - off, t, **kw)
-    su = cc_sparse.safe_uids(uids, cnt)
     k_rows = cc_sparse.sparse_gather_catchup(
-        w_sh, m_sh, v_sh, ls_sh[su - off], su, t, row_offset=off,
-        interpret=True, **kw)
+        w_sh, m_sh, v_sh, ls_sh, uids, t, row_offset=off, interpret=True,
+        **kw)
     for a, b, c in zip(ref_rows, loc_rows, k_rows):
         np.testing.assert_array_equal(np.asarray(a)[:n_real],
                                       np.asarray(b)[:n_real])
@@ -304,7 +304,7 @@ def test_sparse_kernels_row_offset_match_oracle(dim):
         w_sh, m_sh, v_sh, ls_sh, uids, cnt, ref_rows[0], g_rows,
         ref_rows[1], ref_rows[2], t, row_offset=off, **kw)
     k_out = cc_sparse.sparse_update_scatter(
-        jnp.copy(w_sh), jnp.copy(m_sh), jnp.copy(v_sh), su, cnt,
+        jnp.copy(w_sh), jnp.copy(m_sh), jnp.copy(v_sh), uids, cnt,
         ref_rows[0], g_rows, ref_rows[1], ref_rows[2], t, row_offset=off,
         interpret=True, **kw)
     for a, b in zip(ref_out[:3], k_out):
@@ -444,3 +444,26 @@ def test_hybrid_matches_dense_multi_device(hybrid_records, case):
         assert rec["overflow_steps"] >= 1, rec
     else:
         assert rec["overflow_steps"] == 0, rec
+
+
+def test_hybrid_2x2_matches_sparse_in_float64():
+    """sharded_sparse on a (2, 2) mesh and sparse on one device, at
+    deepfm-criteo widths (fields cut to 20,000 ids, field 20 at an odd
+    100,003 so one shard carries a pad row), run in float64 through
+    ``run_ctr``: after 8 steps every param agrees to float64 rounding. In
+    float32 the "data" axis's other summation order is a last-bit
+    difference that Adam can grow over the steps; at float64 the same order
+    leaves nothing of the size of an Adam step, so a gap here is a fault in
+    the mesh path, not rounding."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env.pop("XLA_FLAGS", None)   # the driver sets its own 4-device flag
+    script = os.path.join(REPO, "tests", "mesh_f64_main.py")
+    proc = subprocess.run([sys.executable, script], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    rec = json.loads([line for line in proc.stdout.splitlines()
+                      if line.startswith("{")][-1])
+    assert rec["param_dtypes"] == ["float64"], rec
+    assert rec["max_abs_err"] <= 1e-12, rec
+    assert rec["max_loss_gap"] <= 1e-12, rec

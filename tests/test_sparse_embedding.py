@@ -252,6 +252,24 @@ def test_untouched_rows_not_written_until_flush():
     assert (ls[untouched] == 0).all()
 
 
+def test_flush_passes_moments_through():
+    """flush settles the tables and last_step and hands back the Adam
+    moments as the same arrays: a copy of each moment table would double
+    the embedding state on the device at Criteo widths."""
+    cfg = _cfg(sparse=True)
+    params = ctr.init(jax.random.key(1), cfg)
+    step, init, flush = make_sparse_train_step(cfg, _hp(), use_kernel=False)
+    params, state, _ = step(params, init(params),
+                            next(_dup_heavy_batches(1, seed=2)))
+    _, flushed = flush(params, state)
+    for key in ("m", "v"):
+        for a, b in zip(jax.tree.leaves(flushed[key]),
+                        jax.tree.leaves(state[key])):
+            assert a is b
+    for ls in jax.tree.leaves(flushed["last_step"]):
+        assert (np.asarray(ls) == 1).all()
+
+
 # ---------------------------------------------------------------------------
 # capacity overflow
 # ---------------------------------------------------------------------------
@@ -315,8 +333,8 @@ def test_sparse_kernels_match_reference(dim):
     n_real = int((cnt > 0).sum())
 
     ref_rows = cc_ref.sparse_gather_catchup_reference(w, m, v, ls, uids, t, **kw)
-    k_rows = sparse_gather_catchup(w, m, v, ls, uids, cnt, t,
-                                   use_kernel=True, **kw)
+    k_rows = sparse_gather_catchup(w, m, v, ls, uids, t, use_kernel=True,
+                                   **kw)
     for a, b in zip(ref_rows, k_rows):
         np.testing.assert_allclose(np.asarray(a)[:n_real],
                                    np.asarray(b)[:n_real], atol=1e-6)
@@ -332,8 +350,31 @@ def test_sparse_kernels_match_reference(dim):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
-def test_safe_uids_remaps_pads_to_last_real_slot():
-    uids = jnp.array([2, 9, 30, 50, 50], jnp.int32)   # vocab=50: 2 pads
-    cnt = jnp.array([1.0, 3.0, 1.0, 0.0, 0.0])
-    su = np.asarray(cc_sparse.safe_uids(uids, cnt))
-    np.testing.assert_array_equal(su, [2, 9, 30, 30, 30])
+def test_sparse_kernel_pad_slots_write_nothing():
+    """Pad slots (count 0) are dropped by the kernel path's scatter even when
+    a pad uid minus the shard's row offset lands inside the shard."""
+    rows, dim, off = 40, 8, 20          # shard of global rows 20..59
+    ks = jax.random.split(jax.random.key(3), 5)
+    w = 0.01 * jax.random.normal(ks[0], (rows, dim))
+    m = 0.001 * jax.random.normal(ks[1], (rows, dim))
+    v = 0.0001 * jnp.abs(jax.random.normal(ks[2], (rows, dim)))
+    # two real slots, then pads carrying the global vocab sentinel 50,
+    # which is local row 30 of this shard
+    uids = jnp.array([22, 41, 50, 50], jnp.int32)
+    cnt = jnp.array([2.0, 1.0, 0.0, 0.0])
+    t = jnp.asarray(4, jnp.int32)
+    kw = dict(lr=1e-3, l2=1e-4)
+    w_rows, m_rows, v_rows = cc_sparse.sparse_gather_catchup(
+        w, m, v, jnp.zeros((rows,), jnp.int32), uids, t, row_offset=off,
+        interpret=True, **kw)
+    g_rows = 0.1 * jax.random.normal(ks[3], (4, dim))
+    new = cc_sparse.sparse_update_scatter(
+        jnp.copy(w), jnp.copy(m), jnp.copy(v), uids, cnt, w_rows, g_rows,
+        m_rows, v_rows, t, row_offset=off, interpret=True, **kw)
+    written = np.array([2, 21])
+    kept = np.setdiff1d(np.arange(rows), written)
+    for before, after in zip((w, m, v), new):
+        np.testing.assert_array_equal(np.asarray(after)[kept],
+                                      np.asarray(before)[kept])
+        assert not np.array_equal(np.asarray(after)[written],
+                                  np.asarray(before)[written])

@@ -105,7 +105,7 @@ def test_fused_kernel_equals_substrate_step():
     w_new, m_new, v_new = fused_cowclip_adam(
         table, g_table, counts["t"], jnp.zeros_like(table),
         jnp.zeros_like(table), jnp.asarray(1, jnp.int32),
-        r=1.0, zeta=1e-5, lr=hp.emb_lr, l2=hp.emb_l2,
+        r=1.0, zeta=1e-5, lr=hp.emb_lr, l2=hp.emb_l2, use_kernel=True,
     )
     np.testing.assert_allclose(np.asarray(w_new), np.asarray(via_substrate),
                                rtol=1e-5, atol=1e-8)
@@ -138,7 +138,8 @@ def test_fused_train_step_matches_substrate(dataset):
     state = tx.init(params)
     sub_step = make_train_step(cfg, tx)
 
-    fused_step, fused_init = make_fused_train_step(cfg, hp, zeta=1e-5)
+    fused_step, fused_init = make_fused_train_step(cfg, hp, zeta=1e-5,
+                                                   use_kernel=True)
     fstate = fused_init(params)
 
     b = next(iterate_batches(dataset, 512, seed=9))
