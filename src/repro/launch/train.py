@@ -208,11 +208,16 @@ def run_ctr(args, data=None):
 
     trace_ctx = contextlib.nullcontext()
     if args.profile_trace:
-        # per-phase timeline of the train step: the named_scope annotations
-        # (dedup_allgather / embed_lookup_psum / tower_fwd_bwd /
-        # rowgrad_psum / row_update / ...) show up as labeled slices, so
-        # collective/compute overlap is read off the trace directly.
-        # Open the perfetto .gz under <dir>/plugins/perfetto in ui.perfetto.dev.
+        # per-phase timeline of the train step: each device op carries its
+        # named_scope in its op_name. The sparse step's are
+        # loop.STEP_SCOPES (dedup / row_gather_catchup / tower_fwd_bwd /
+        # row_update_scatter / dense_update / step_counters); the sharded
+        # steps add dedup_allgather / embed_lookup_psum / rowgrad_psum /
+        # row_update / ..., so collective/compute overlap is read off the
+        # trace directly. The input feed writes host spans prefetch.stack
+        # (worker thread), prefetch.wait and prefetch.put (consumer), each
+        # with the chunk's number. Open the perfetto .gz under
+        # <dir>/plugins/perfetto in ui.perfetto.dev.
         trace_ctx = jax.profiler.trace(args.profile_trace,
                                        create_perfetto_trace=True)
         print(f"[train] profiling to {args.profile_trace} (perfetto trace)")

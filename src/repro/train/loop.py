@@ -22,6 +22,13 @@ from ..models import ctr
 from ..models import embedding as embedding_lib
 from . import metrics
 
+# The sparse step's phases, each a ``jax.named_scope`` in the order they
+# run. Every device op of the step carries the innermost of these names in
+# its ``op_name`` (the compiled HLO's metadata), so a profiler trace can put
+# each op's time down to its phase.
+STEP_SCOPES = ("dedup", "row_gather_catchup", "tower_fwd_bwd",
+               "row_update_scatter", "dense_update", "step_counters")
+
 
 def make_train_step(cfg: ctr.CTRConfig, tx: GradientTransformation):
     """Returns jit'd (params, opt_state, batch) -> (params, opt_state, aux).
@@ -162,6 +169,10 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
     in a per-row ``last_step`` array and is replayed on next touch (or by
     ``flush``), keeping the path exactly equivalent to the dense one.
 
+    Each phase runs under one of ``STEP_SCOPES``. Besides ``loss`` the aux
+    holds ``catchup_depth_max``, the deepest pending catch-up among the
+    touched rows (int32).
+
     Returns ``(step, init, flush)``; ``flush(params, state)`` applies all
     pending decay (needed before eval / checkpoint / comparing against the
     dense path).
@@ -190,17 +201,20 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
 
     def step_impl(params, state, batch):
         t = state["step"] + 1
-        uniq = ctr.unique_batch(cfg, batch["ids"])
-        utree = _uniq_tree(params["embed"], uniq)
+        with jax.named_scope("dedup"):
+            uniq = ctr.unique_batch(cfg, batch["ids"])
+            utree = _uniq_tree(params["embed"], uniq)
 
-        # diagnostic: deepest pending-decay catch-up among this step's
-        # touched rows (0 when every touched id was also in the last batch)
-        depth_tree = jax.tree.map(
-            lambda u, ls: jnp.max(jnp.where(
-                u.counts > 0,
-                (t - 1) - ls[jnp.minimum(u.uids, ls.shape[0] - 1)], 0)),
-            utree, state["last_step"], is_leaf=_is_uniq)
-        depth = jnp.max(jnp.stack(jax.tree.leaves(depth_tree)))
+        with jax.named_scope("step_counters"):
+            # diagnostic: deepest pending-decay catch-up among this step's
+            # touched rows (0 when every touched id was also in the last
+            # batch)
+            depth_tree = jax.tree.map(
+                lambda u, ls: jnp.max(jnp.where(
+                    u.counts > 0,
+                    (t - 1) - ls[jnp.minimum(u.uids, ls.shape[0] - 1)], 0)),
+                utree, state["last_step"], is_leaf=_is_uniq)
+            depth = jnp.max(jnp.stack(jax.tree.leaves(depth_tree)))
 
         # gather + apply pending decay (closed form, O(1) in depth) so the
         # forward sees rows exactly as the dense path would at step t
@@ -213,9 +227,11 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
             )
         w_rows, m_rows, v_rows = _unzip3(caught, params["embed"])
 
-        loss, (g_rows, g_dense) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1))(
-            w_rows, params["dense"], uniq, batch["dense"], batch["labels"])
+        with jax.named_scope("tower_fwd_bwd"):
+            loss, (g_rows, g_dense) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1))(
+                w_rows, params["dense"], uniq, batch["dense"],
+                batch["labels"])
 
         # CowClip -> coupled L2 -> Adam on the touched rows, scattered back;
         # untouched rows keep accruing lazy decay via last_step
@@ -237,10 +253,13 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
         new_embed = jax.tree.map(
             lambda w, p: w.astype(p.dtype), new_embed, params["embed"])
 
-        d_updates, d_state = dense_tx.update(
-            g_dense, state["dense"], params["dense"])
-        new_dense = jax.tree.map(
-            lambda p, u: p + u.astype(p.dtype), params["dense"], d_updates)
+        with jax.named_scope("dense_update"):
+            d_updates, d_state = dense_tx.update(
+                g_dense, state["dense"], params["dense"])
+            new_dense = jax.tree.map(
+                lambda p, u: p + u.astype(p.dtype), params["dense"],
+                d_updates)
+
         new_state = {"step": t, "m": new_m, "v": new_v, "last_step": new_ls,
                      "dense": d_state}
         return {"embed": new_embed, "dense": new_dense}, new_state, {
