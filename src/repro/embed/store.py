@@ -108,7 +108,21 @@ class EmbeddingStore:
             raise ValueError("cold_store='mmap' needs cold_dir "
                              "(the on-disk table directory)")
 
-    def describe(self) -> str:
+    def describe(self, cfg=None) -> str:
+        """One line for logs. Given the model's ``cfg``, the sparse
+        placement adds how many tables' Adam moments it stores packed
+        (``kernels.cowclip.ref.packs``) and their bytes unpacked and
+        packed."""
+        if self.placement == "sparse" and cfg is not None:
+            from ..kernels.cowclip import ref as cc_ref
+            from ..models import ctr
+
+            embed = jax.eval_shape(
+                lambda: ctr.init(jax.random.key(0), cfg))["embed"]
+            n, total, raw, stored = cc_ref.packed_moment_bytes(
+                jax.tree.leaves(embed))
+            return (f"sparse(Adam moments of {n} of {total} tables packed "
+                    f"128 lanes wide: {raw} -> {stored} bytes)")
         if self.placement in ("sharded", "sharded_sparse"):
             from . import sharded as shard_lib
             mesh = self.mesh if self.mesh is not None else shard_lib.default_mesh()

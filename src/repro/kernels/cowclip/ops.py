@@ -55,8 +55,9 @@ def sparse_gather_catchup(
 
     ``uids`` are the slot uids (pads out of range). ``row_offset`` is the
     shard-offset form: ``w``/``m``/``v``/``last_step`` are one row-shard
-    and ``uids`` global ids of rows that shard owns. Returns f32 (w_rows,
-    m_rows, v_rows).
+    and ``uids`` global ids of rows that shard owns. ``m``/``v`` are
+    ``[rows, dim]`` or packed (``ref.pack_rows``), told apart by shape.
+    Returns f32 (w_rows, m_rows, v_rows).
     """
     kw = dict(lr=lr, l2=l2, b1=b1, b2=b2, eps=eps, row_offset=row_offset)
     if not use_kernel:

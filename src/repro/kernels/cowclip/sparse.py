@@ -29,6 +29,9 @@ need ``(1, dim)`` blocks or a one-row DMA out of HBM, and the TPU compiler
 accepts neither at CTR widths (dim 10 and 1 are not multiples of its
 (8, 128) tiling), so the row movement is XLA's gather and scatter.
 
+The moment tables come ``[rows, dim]`` or packed (``ref.pack_rows``); the
+row movement reads the form from the table's shape.
+
 Pad slots (capacity > n_unique, count 0) carry out-of-range uids: their
 gathered rows are garbage that nothing reads, and the scatter drops them
 (``mode="drop"``, with count-0 slots forced out of range).
@@ -50,6 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .cowclip import cowclip_adam_update, default_block_rows
+from .ref import gather_rows, scatter_rows
 
 
 def _catchup_kernel(lim_ref, w_ref, ls_ref, w_out, *, factor):
@@ -64,8 +68,8 @@ def _catchup_kernel(lim_ref, w_ref, ls_ref, w_out, *, factor):
 
 def sparse_gather_catchup(
     w: jnp.ndarray,           # [rows, dim] table (or one shard of it)
-    m: jnp.ndarray,           # [rows, dim] Adam first moment
-    v: jnp.ndarray,           # [rows, dim] Adam second moment
+    m: jnp.ndarray,           # [rows, dim] or packed Adam first moment
+    v: jnp.ndarray,           # [rows, dim] or packed Adam second moment
     last_step: jnp.ndarray,   # [rows] int32 step each row was last updated
     uids: jnp.ndarray,        # [cap] int32 slot uids (pads out of range)
     step: jnp.ndarray,        # scalar int32 t: catch rows up through t-1
@@ -101,13 +105,14 @@ def sparse_gather_catchup(
         out_shape=jax.ShapeDtypeStruct((cap, dim), jnp.float32),
         interpret=interpret,
     )(lim, w[loc], last_step[loc].astype(jnp.int32)[:, None])
-    return w_rows, m[loc].astype(jnp.float32), v[loc].astype(jnp.float32)
+    return (w_rows, gather_rows(m, loc, dim).astype(jnp.float32),
+            gather_rows(v, loc, dim).astype(jnp.float32))
 
 
 def sparse_update_scatter(
     w: jnp.ndarray,           # [rows, dim] table or shard
-    m: jnp.ndarray,           # [rows, dim] Adam first moment
-    v: jnp.ndarray,           # [rows, dim] Adam second moment
+    m: jnp.ndarray,           # [rows, dim] or packed Adam first moment
+    v: jnp.ndarray,           # [rows, dim] or packed Adam second moment
     uids: jnp.ndarray,        # [cap] int32 slot uids
     counts: jnp.ndarray,      # [cap] f32 per-slot batch counts (0 on pads)
     w_rows: jnp.ndarray,      # [cap, dim] caught-up rows (f32)
@@ -137,7 +142,9 @@ def sparse_update_scatter(
         interpret=interpret)
     # pad slots (count 0) are forced out of range: with a row_offset the
     # raw pad uid (vocab) minus the offset could otherwise land in range
-    loc = jnp.where(counts > 0, uids - row_offset, w.shape[0])
-    return (w.at[loc].set(w_new.astype(w.dtype), mode="drop"),
-            m.at[loc].set(m_new.astype(m.dtype), mode="drop"),
-            v.at[loc].set(v_new.astype(v.dtype), mode="drop"))
+    keep = counts > 0
+    loc = uids - row_offset
+    return (w.at[jnp.where(keep, loc, w.shape[0])].set(
+                w_new.astype(w.dtype), mode="drop"),
+            scatter_rows(m, loc, m_new, keep),
+            scatter_rows(v, loc, v_new, keep))

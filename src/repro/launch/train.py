@@ -199,7 +199,7 @@ def run_ctr(args, data=None):
                  else "epochs")
     print(f"[train] {args.model}: {n_params/1e6:.1f}M params "
           f"({len(tr)} train rows, batch {args.batch}, rule {args.rule}, "
-          f"embedding store {store.describe()}, engine {engine_desc}, "
+          f"embedding store {store.describe(cfg)}, engine {engine_desc}, "
           f"mode {mode_desc}, compute {args.compute_dtype})")
 
     # every placement goes through the one EmbeddingStore bundle interface

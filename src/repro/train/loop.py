@@ -173,22 +173,34 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
     holds ``catchup_depth_max``, the deepest pending catch-up among the
     touched rows (int32).
 
+    The Adam moments of a table whose rows are narrow enough
+    (``kernels.cowclip.ref.packs``) are stored packed, ``k = 128 // dim``
+    rows to a 128-lane row (``ref.pack_rows``; ``ref.unpack_rows`` gives
+    ``[V, dim]`` back): the row gathers and scatters then touch one lane
+    row per id. The weights keep their public ``[V, dim]`` form.
+
     Returns ``(step, init, flush)``; ``flush(params, state)`` applies all
     pending decay (needed before eval / checkpoint / comparing against the
     dense path).
     """
     from ..core import optim as optim_lib
     from ..kernels import cowclip as cc_kernels
+    from ..kernels.cowclip import ref as cc_ref
 
     if dense_tx is None:
         dense_tx = optim_lib.adam(hp.dense_lr, l2=hp.dense_l2)
     adam_kw = dict(lr=hp.emb_lr, l2=hp.emb_l2, b1=b1, b2=b2, eps=eps)
 
+    def zero_moment(w):
+        if cc_ref.packs(w.shape[1]):
+            return jnp.zeros(cc_ref.packed_shape(*w.shape), w.dtype)
+        return jnp.zeros_like(w)
+
     def init(params):
         return {
             "step": jnp.zeros((), jnp.int32),
-            "m": jax.tree.map(jnp.zeros_like, params["embed"]),
-            "v": jax.tree.map(jnp.zeros_like, params["embed"]),
+            "m": jax.tree.map(zero_moment, params["embed"]),
+            "v": jax.tree.map(zero_moment, params["embed"]),
             "last_step": jax.tree.map(
                 lambda t: jnp.zeros((t.shape[0],), jnp.int32),
                 params["embed"]),
@@ -274,9 +286,10 @@ def _make_lazy_flush(adam_kw: dict):
     current step, then stamp ``last_step = step`` everywhere. Idempotent —
     a second call replays zero iterations and rewrites identical values.
 
-    The Adam moments pass through as they are (decay-only steps never move
-    them), outside the jitted settle: as its outputs they would be fresh
-    copies of both moment tables, doubling the state on the device."""
+    The Adam moments pass through as they are, in whichever form the
+    placement stores them (decay-only steps never move them), outside the
+    jitted settle: as its outputs they would be fresh copies of both moment
+    tables, doubling the state on the device."""
     from ..core import optim as optim_lib
 
     @jax.jit
