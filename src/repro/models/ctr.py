@@ -71,6 +71,11 @@ class CTRConfig:
 
 MODEL_NAMES = ("wd", "deepfm", "dcn", "dcnv2")
 
+# The cross network of ``dcn`` and ``dcnv2`` runs under this
+# ``jax.named_scope``, forward and backward, so a trace can tell it apart
+# from the rest of the tower.
+CROSS_SCOPE = "cross"
+
 
 def _dense_init(key, fan_in, fan_out):
     """Kaiming-normal for ReLU towers (He et al. 2015, as in the paper)."""
@@ -195,17 +200,19 @@ def _combine(
     if cfg.name == "dcn":
         x = x0
         cp = dense_params["cross"]
-        for i in range(cfg.n_cross):
-            # x_{l+1} = x0 * (x_l . w_l) + b_l + x_l
-            x = x0 * (x @ cp[f"w{i}"])[:, None] + cp[f"b{i}"] + x
+        with jax.named_scope(CROSS_SCOPE):
+            for i in range(cfg.n_cross):
+                # x_{l+1} = x0 * (x_l . w_l) + b_l + x_l
+                x = x0 * (x @ cp[f"w{i}"])[:, None] + cp[f"b{i}"] + x
         combined = jnp.concatenate([x, deep], axis=-1)
         return _apply_mlp(dense_params["combine"], combined, 1)[:, 0]
     if cfg.name == "dcnv2":
         x = x0
         cp = dense_params["cross"]
-        for i in range(cfg.n_cross):
-            # x_{l+1} = x0 ⊙ (W_l x_l + b_l) + x_l
-            x = x0 * (x @ cp[f"w{i}"] + cp[f"b{i}"]) + x
+        with jax.named_scope(CROSS_SCOPE):
+            for i in range(cfg.n_cross):
+                # x_{l+1} = x0 ⊙ (W_l x_l + b_l) + x_l
+                x = x0 * (x @ cp[f"w{i}"] + cp[f"b{i}"]) + x
         combined = jnp.concatenate([x, deep], axis=-1)
         return _apply_mlp(dense_params["combine"], combined, 1)[:, 0]
     raise ValueError(cfg.name)

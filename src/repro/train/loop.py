@@ -20,6 +20,7 @@ logger = logging.getLogger(__name__)
 from ..data.synthetic import CTRDataset, iterate_batches
 from ..models import ctr
 from ..models import embedding as embedding_lib
+from ..models.ctr import CROSS_SCOPE  # noqa: F401  (see below)
 from . import metrics
 
 # The sparse step's phases, each a ``jax.named_scope`` in the order they
@@ -28,6 +29,10 @@ from . import metrics
 # each op's time down to its phase.
 STEP_SCOPES = ("dedup", "row_gather_catchup", "tower_fwd_bwd",
                "row_update_scatter", "dense_update", "step_counters")
+
+# ``CROSS_SCOPE``, defined in ``models/ctr.py`` and imported above, names
+# the cross network of ``dcn`` and ``dcnv2`` inside ``tower_fwd_bwd``. It
+# is apart from ``STEP_SCOPES`` because the other models have no cross ops.
 
 
 def make_train_step(cfg: ctr.CTRConfig, tx: GradientTransformation):
