@@ -533,8 +533,8 @@ def make_migrate_device_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
 
     def step_impl(dense_params, dense_opt, hot, t, batch, plan):
         # the on-device dedup is O(batch) and must agree with the
-        # planner's host replica (np.unique and jnp.unique(size=...) both
-        # emit sorted-ascending uids padded with vocab)
+        # planner's host replica (np.unique and embedding.sort_unique both
+        # emit np.unique's sorted-ascending uids, padded with vocab)
         uniq = ctr.unique_batch(cfg, batch["ids"])
         groups = list(hot["w"].keys())
 

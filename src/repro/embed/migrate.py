@@ -163,8 +163,9 @@ class MigrationPlanner:
         V, C = self.vocab[f], self.caps[f]
         U = self._unique_cap(f, col.shape[0])
 
-        # dedup — np.unique and jnp.unique(size=U, fill_value=V) agree:
-        # sorted ascending uids, pads hold V with count 0
+        # dedup — np.unique and the device's embedding.sort_unique (U
+        # slots, fill V) agree: np.unique's sorted ascending uids, pads
+        # hold V with count 0
         uids_r, counts_r = np.unique(col, return_counts=True)
         if uids_r.shape[0] > U:
             raise ValueError(

@@ -20,7 +20,11 @@ in it, so the update path can work on ``[n_unique, dim]`` gathered rows
 instead of streaming the whole ``[vocab, dim]`` table (the layout every
 terabyte-scale CTR system uses; arXiv:2201.05500, arXiv:2209.05310).
 ``unique_ids`` deduplicates one field's batch column with a **static padded
-capacity** (jit-stable shapes):
+capacity** (jit-stable shapes), through ``sort_unique``: three sorts, one
+cumsum and elementwise ops, no scatter and no gather. Its output is
+``jnp.unique(..., size=capacity, fill_value=vocab, return_inverse=True,
+return_counts=True)``'s bit for bit, and so ``np.unique``'s sorted-ascending
+ids with ``vocab`` pads:
 
 * slots ``[0, n_unique)`` hold the batch's distinct ids ascending; padding
   slots hold ``vocab`` (one past the last row) so scatters with
@@ -40,6 +44,7 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def init_field_tables(
@@ -104,17 +109,43 @@ class UniqueField(NamedTuple):
         return jnp.sum((self.counts > 0).astype(jnp.int32))
 
 
+def sort_unique(col: jnp.ndarray, vocab: int, capacity: int):
+    """``(uids, inv, counts)`` of a 1-D column, all int32, as
+    ``jnp.unique(col, size=capacity, fill_value=vocab, return_inverse=True,
+    return_counts=True)`` gives them, from sorts alone.
+
+    1. Sort the ids, carrying their positions: ``s``, ``perm``.
+    2. ``first`` marks each run's first slot; ``slot = cumsum(first) - 1``
+       is each sorted element's slot.
+    3. Sort the run starts (every other position keyed past the end) with
+       their ids: the first ``capacity`` are the uids, and the gaps between
+       consecutive starts, the last one closed by ``b``, the counts.
+    4. Sort ``slot`` back into batch order by ``perm``: the inverse.
+
+    A slot past ``capacity`` (overflow) keeps its rank as ``inv``, which the
+    forward's gather clips to the last kept slot.
+    """
+    b = col.shape[0]
+    iota = lax.iota(jnp.int32, b)
+    s, perm = lax.sort((col, iota), num_keys=1, is_stable=False)
+    first = jnp.concatenate([jnp.ones((1,), bool), s[1:] != s[:-1]])
+    slot = jnp.cumsum(first, dtype=jnp.int32) - 1
+    starts, vals = lax.sort((jnp.where(first, iota, b), s), num_keys=1,
+                            is_stable=False)
+    if capacity + 1 > b:
+        starts = jnp.pad(starts, (0, capacity + 1 - b), constant_values=b)
+        vals = jnp.pad(vals, (0, capacity + 1 - b))
+    starts = starts[:capacity + 1]
+    uids = jnp.where(starts[:capacity] < b, vals[:capacity], vocab)
+    _, inv = lax.sort((perm, slot), num_keys=1, is_stable=False)
+    return (uids.astype(jnp.int32), inv,
+            starts[1:] - starts[:capacity])
+
+
 def unique_ids(ids_col: jnp.ndarray, vocab: int, capacity: int) -> UniqueField:
     """Deduplicate one field's batch column into a padded-capacity slot set."""
-    uids, inv, counts = jnp.unique(
-        ids_col, size=capacity, fill_value=vocab,
-        return_inverse=True, return_counts=True,
-    )
-    return UniqueField(
-        uids=uids.astype(jnp.int32),
-        inv=inv.reshape(ids_col.shape).astype(jnp.int32),
-        counts=counts.astype(jnp.float32),
-    )
+    uids, inv, counts = sort_unique(ids_col, vocab, capacity)
+    return UniqueField(uids=uids, inv=inv, counts=counts.astype(jnp.float32))
 
 
 def batch_unique(

@@ -81,6 +81,67 @@ def test_unique_ids_slots_counts_and_pads():
                                   np.asarray(ids))
 
 
+def _zipf_col(rng, batch, vocab):
+    return np.minimum(rng.zipf(1.1, size=batch) - 1, vocab - 1)
+
+
+# (column kind, batch, vocab, capacity)
+DEDUP_CASES = {
+    "zipf_heavy_dups": ("zipf", 4096, 100_000, 4096),
+    "all_distinct": ("distinct", 512, 100_000, 512),
+    "all_equal": ("equal", 512, 100_000, 512),
+    "overflow": ("zipf", 4096, 100_000, 37),
+    "overflow_all_distinct": ("distinct", 512, 100_000, 100),
+    "capacity_is_vocab_below_batch": ("zipf", 2048, 300, 300),
+    "capacity_above_batch": ("zipf", 64, 100_000, 200),
+    "batch_of_one": ("zipf", 1, 10, 1),
+    "batch_of_one_wide": ("zipf", 1, 10, 4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DEDUP_CASES))
+def test_unique_ids_equals_jnp_and_np_unique(case):
+    """The sorts-only dedup gives ``jnp.unique(size=, fill_value=,
+    return_inverse=True, return_counts=True)``'s uids, inverse and counts
+    bit for bit, and ``np.unique``'s: sorted ids, ``vocab`` pads, each
+    element's rank among the distinct ids (``>= capacity`` when its id was
+    dropped on overflow) and the kept ids' occurrence counts."""
+    kind, batch, vocab, cap = DEDUP_CASES[case]
+    rng = np.random.default_rng(sum(map(ord, case)))
+    col = {"zipf": lambda: _zipf_col(rng, batch, vocab),
+           "distinct": lambda: rng.permutation(vocab)[:batch],
+           "equal": lambda: np.full(batch, rng.integers(vocab))}[kind]()
+    col = col.astype(np.int32)
+    u = jax.jit(embedding.unique_ids, static_argnums=(1, 2))(
+        jnp.asarray(col), vocab, cap)
+
+    ju, jinv, jcnt = jnp.unique(jnp.asarray(col), size=cap, fill_value=vocab,
+                                return_inverse=True, return_counts=True)
+    np.testing.assert_array_equal(np.asarray(u.uids), np.asarray(ju))
+    np.testing.assert_array_equal(np.asarray(u.inv),
+                                  np.asarray(jinv).reshape(-1))
+    np.testing.assert_array_equal(np.asarray(u.counts),
+                                  np.asarray(jcnt).astype(np.float32))
+
+    nu, ninv, ncnt = np.unique(col, return_inverse=True, return_counts=True)
+    keep = min(cap, len(nu))
+    want_uids = np.full(cap, vocab, np.int32)
+    want_uids[:keep] = nu[:keep]
+    want_counts = np.zeros(cap, np.float32)
+    want_counts[:keep] = ncnt[:keep]
+    np.testing.assert_array_equal(np.asarray(u.uids), want_uids)
+    np.testing.assert_array_equal(np.asarray(u.inv), ninv.reshape(-1))
+    np.testing.assert_array_equal(np.asarray(u.counts), want_counts)
+    assert u.uids.dtype == u.inv.dtype == jnp.int32
+    assert u.counts.dtype == jnp.float32
+    assert u.uids.shape == (cap,) and u.inv.shape == (batch,)
+    if len(nu) > cap:        # overflow: the smallest ids kept, the rest out
+        dropped = col > nu[cap - 1]
+        assert dropped.any()
+        assert (np.asarray(u.inv)[dropped] >= cap).all()
+        assert (np.asarray(u.inv)[~dropped] < cap).all()
+
+
 def test_field_counts_match_dense_segment_sum():
     rng = np.random.default_rng(3)
     ids = np.stack([rng.integers(0, v, size=128) for v in VOCABS], axis=1)

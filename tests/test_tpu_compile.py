@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiles for a TPU v5e chip at production widths.
+"""Every Pallas kernel compiles for a TPU v5e chip at production widths,
+and the sparse path's dedup compiles there without scatter or gather.
 
 Nothing here runs on a chip: the TPU compiler, which ships with jaxlib,
 compiles for a described v5e topology while the process stays on the CPU
@@ -11,6 +12,7 @@ all import this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,7 @@ from repro.configs.deepfm_criteo import CRITEO_VOCABS
 from repro.kernels.cowclip import cowclip as cc_dense
 from repro.kernels.cowclip import sparse as cc_sparse
 from repro.kernels.wkv6.wkv6 import chunked_wkv6
+from repro.models import embedding
 
 BIGGEST_VOCAB = max(CRITEO_VOCABS)          # 10,131,227 rows
 CAP = 8192                                  # unique slots at batch 8K
@@ -86,3 +89,17 @@ def test_wkv6_kernel_compiles(one_chip):
     seq = ((bh, s, n), jnp.bfloat16)
     _compile(chunked_wkv6, one_chip, seq, seq, seq, seq,
              ((bh, n), jnp.bfloat16))
+
+
+def test_unique_ids_compiles_without_scatter_or_gather(one_chip):
+    """One field's dedup at batch 131,072 (the b128k cells) is sorts, a
+    cumsum and elementwise ops: no scatter or gather, which is where
+    ``jnp.unique``'s lowering spent its time on the chip."""
+    batch = 131_072
+    fn = functools.partial(embedding.unique_ids, vocab=BIGGEST_VOCAB,
+                           capacity=batch)
+    col = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    text = jax.jit(fn).lower(col).compile().as_text()
+    opcodes = set(re.findall(r" ([a-z][a-z-]*)\(", text))
+    assert "sort" in opcodes
+    assert not {"scatter", "gather"} & opcodes
