@@ -2,7 +2,7 @@
 """Smoke run of the CTR training path on TPU chips, through the CLI's own
 entry points (``repro.launch.train.parse_args`` / ``run_ctr``).
 
-    python chip_smoke.py            # one chip: phases a-d
+    python chip_smoke.py            # one chip: phases a, b and d
     python chip_smoke.py --chips 4  # four chips: the mesh phase only
 
 Phases on one chip:
@@ -11,9 +11,6 @@ Phases on one chip:
      features, dim 10, MLP 3x400), sparse placement, CowClip rule, scan
      engine x8, batch 8,192, 16 steps.
   b  The same model at the paper's top batch, 131,072, for 2 steps.
-  c  From (a)'s state, one step through the Pallas row kernels and one
-     through the default XLA update: the new tables and moments agree to
-     1e-6 relative.
   d  Five default fields, base l2 0, base lr 1e-3: the sparse and fused
      placements against the dense substrate oracle over 8 steps, params
      within 1e-5.
@@ -46,7 +43,6 @@ CRITEO = ["--task", "ctr", "--arch", "deepfm-criteo", "--base-batch", "1024",
           "--base-lr", "1e-4", "--base-l2", "1e-5"]
 SMOKE_ROWS = 262_144          # one dataset serves phases a and b
 EXACT_TOL = 1e-5              # the CPU tests' param tolerance
-KERNEL_RTOL = 1e-6
 
 
 def fail(msg: str):
@@ -107,61 +103,6 @@ def train_phase(tag, argv, data, clock, jax, *, steps):
     }
     print(f"[{tag}] " + json.dumps(fig), flush=True)
     return res
-
-
-def rel_err(a, b) -> float:
-    """Max |a - b| over max |b| (host arrays); 0 or 1 for integer arrays,
-    which must be equal."""
-    import numpy as np
-
-    a, b = np.asarray(a), np.asarray(b)
-    if not np.issubdtype(b.dtype, np.floating):
-        return float(not np.array_equal(a, b))
-    scale = max(float(np.max(np.abs(b))), float(np.finfo(b.dtype).tiny))
-    return float(np.max(np.abs(a - b))) / scale
-
-
-def kernel_vs_xla(argv, data, res, clock, jax):
-    """Phase c: one step from ``res``'s state through each row update.
-
-    Two copies of the Criteo-width state do not fit one chip beside a step,
-    so the start state waits on the host while the XLA step runs, and the
-    two results are compared on the host."""
-    import jax.numpy as jnp
-
-    from repro.embed import store_for
-    from repro.launch import train as train_lib
-
-    args = train_lib.parse_args(argv)
-    cfg = train_lib.make_ctr_config(args, data, "sparse")
-    store = store_for(cfg)
-    n_train = len(data.split(0.9)[0])
-    kernel = train_lib.make_ctr_bundle(args, cfg, store, n_train,
-                                       use_kernel=True)
-    xla = train_lib.make_ctr_bundle(args, cfg, store, n_train)
-    rows = slice(0, args.batch)
-    batch = {"ids": jnp.asarray(data.ids[rows]),
-             "dense": jnp.asarray(data.dense[rows]),
-             "labels": jnp.asarray(data.labels[rows])}
-
-    start = jax.device_get((res.params, res.opt_state))
-    # both steps donate their state: this one frees res's
-    px, sx, aux_x = xla.step(res.params, res.opt_state, batch)
-    want, loss_x = jax.device_get(((px, sx), aux_x["loss"]))
-    del px, sx, aux_x
-    pk, sk, aux_k = kernel.step(*jax.device_put(start), batch)
-    got, loss_k = jax.device_get(((pk, sk), aux_k["loss"]))
-    del pk, sk, aux_k, start
-
-    leaves_k, leaves_x = jax.tree.leaves(got), jax.tree.leaves(want)
-    check(len(leaves_k) == len(leaves_x), "[c] the two states differ in form")
-    worst = max(rel_err(a, b) for a, b in zip(leaves_k, leaves_x))
-    fig = {"max_rel_err": worst, "loss_kernel": float(loss_k),
-           "loss_xla": float(loss_x), "compile_s": clock.lap(),
-           **memory(jax, jax.devices()[0])}
-    print("[c] " + json.dumps(fig), flush=True)
-    check(worst <= KERNEL_RTOL, f"[c] kernel vs XLA rel err {worst} "
-          "(integer leaves such as last_step count 1 when unequal)")
 
 
 def max_abs_err(jax, a, b) -> tuple:
@@ -301,9 +242,7 @@ def main(argv=None) -> int:
             "--epochs", "1", "--samples", str(SMOKE_ROWS),
             "--seed", str(args.seed)]
         data = train_lib.make_ctr_data(train_lib.parse_args(argv_a))
-        res = train_phase("a", argv_a, data, clock, jax, steps=16)
-        kernel_vs_xla(argv_a, data, res, clock, jax)
-        del res
+        train_phase("a", argv_a, data, clock, jax, steps=16)
         argv_b = list(argv_a)
         argv_b[argv_b.index("--batch") + 1] = "131072"
         argv_b[argv_b.index("--steps") + 1] = "2"

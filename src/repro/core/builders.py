@@ -278,7 +278,7 @@ def build_train_step(
 
       substrate      : composable GradientTransformation chain (the oracle);
                        dense placement
-      fused          : dense fused Pallas CowClip+L2+Adam kernel per table;
+      fused          : dense fused jnp CowClip+L2+Adam update per table;
                        dense placement
       sparse         : unique-id gather -> fused row update -> scatter, with
                        lazy L2 decay (O(batch) update traffic)
@@ -303,13 +303,16 @@ def build_train_step(
                        select the frequency policy for either variant.
 
     ``path=None`` honors the config knobs: ``cfg.placement`` if set, else
-    ``cfg.sparse`` selects "sparse", otherwise "substrate".
-    ``use_kernel=True`` runs the embedding row update through the Pallas
-    kernels where the placement has them (compiled on a TPU, interpret mode
-    elsewhere — a correctness harness, far too slow for CPU training); the
-    default is the jnp/XLA update on every backend. The dense tower always
-    runs the substrate Adam (with optional warmup).
+    ``cfg.sparse`` selects "sparse", otherwise "substrate". The dense
+    tower always runs the substrate Adam (with optional warmup).
+
+    ``use_kernel`` accepts ``False`` only, for callers that still pass it:
+    every placement runs the one jnp row update, compiled by XLA.
     """
+    if use_kernel:
+        raise ValueError("use_kernel=True: the Pallas CowClip row kernels "
+                         "were removed; every placement runs the XLA row "
+                         "update (leave use_kernel out)")
     from ..embed.store import store_for  # deferred: embed imports core
 
     store = store_for(cfg, path=path, mesh=mesh, partition=partition,
@@ -318,5 +321,4 @@ def build_train_step(
                       half_life=half_life)
     return store.make_bundle(
         cfg, hp, clip_kind=clip_kind, r=r, zeta=zeta, clip_t=clip_t,
-        warmup_steps=warmup_steps, b1=b1, b2=b2, eps=eps,
-        use_kernel=use_kernel)
+        warmup_steps=warmup_steps, b1=b1, b2=b2, eps=eps)

@@ -195,7 +195,7 @@ def add_decayed_weights(weight_decay: float) -> GradientTransformation:
 def decay_factor(lr: float, l2: float) -> float:
     """The per-step absent-row multiplier ``1 - lr * l2``, f32-rounded.
 
-    Every path (substrate transform, Pallas kernels, jnp oracles, sharded
+    Every path (substrate transform, fused row updates, sharded
     placements) derives the factor through this one helper so the rounding
     is identical everywhere. Returned as a Python float (exactly
     representable in f32) so it can also serve as a static kernel param.
@@ -359,7 +359,7 @@ def lazy_coupled_adam(
     catch-up (see the decay section above). The absent-row update is emitted
     as ``w*factor - w``, which is exact (Sterbenz) for factors near 1, so
     ``apply_updates``' ``w + u`` lands on fl(w * factor) bit-for-bit — the
-    same value the fused kernels write directly.
+    same value the fused row updates write directly.
 
     Requires the per-id batch ``counts=`` extra (shape [vocab] per table,
     matching the params subtree); raises ValueError without it.

@@ -52,10 +52,6 @@ Caveats:
 * ``state["last_step"]`` (the cold tier's view) is stale for resident
   rows, so ``embed.store.max_pending_depth`` is an *upper bound* here —
   still 0 right after ``flush``, which reconciles both tiers.
-* ``use_kernel`` is accepted for signature uniformity and ignored: rows
-  are pre-assembled from the two tiers, and the row update is the shared
-  reference math (``core.optim.sparse_adam_rows`` et al.) regardless of
-  backend.
 """
 
 from __future__ import annotations
@@ -196,8 +192,7 @@ def residency_map_bytes(state) -> int:
 
 def make_hotcold_train_step(cfg: ctr.CTRConfig, hp, *, capacity: int = 4096,
                             r: float = 1.0, zeta: float = 1e-5,
-                            dense_tx=None, use_kernel: bool = False,
-                            clip: bool = True, b1: float = 0.9,
+                            dense_tx=None, clip: bool = True, b1: float = 0.9,
                             b2: float = 0.999, eps: float = 1e-8,
                             admission: str = "cumulative",
                             half_life: int = 0):
@@ -216,7 +211,6 @@ def make_hotcold_train_step(cfg: ctr.CTRConfig, hp, *, capacity: int = 4096,
     reconciles both tiers and settles all pending decay (idempotent);
     residency and frequencies survive a flush.
     """
-    del use_kernel  # rows are pre-assembled; the row math is backend-free
     from ..train import metrics
 
     if dense_tx is None:

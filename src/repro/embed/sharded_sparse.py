@@ -257,8 +257,8 @@ def catchup_depth_slots(ls, uloc, counts, t):
 
 
 def update_phase(w, m, v, ls, uloc, counts, overflow, g_slots, g_full,
-                 cnt_full, t, *, use_kernel=False, clip=True, r=1.0,
-                 zeta=1e-5, lr=1e-4, l2=1e-5, b1=0.9, b2=0.999, eps=1e-8):
+                 cnt_full, t, *, clip=True, r=1.0, zeta=1e-5, lr=1e-4,
+                 l2=1e-5, b1=0.9, b2=0.999, eps=1e-8):
     """Post-backward phase on one (field, group) shard, starting from the
     *raw* (w, m, v, ls) tensors — the forward never scatters into them
     (its lookup applies pending decay inline), so this phase owns the whole
@@ -289,12 +289,12 @@ def update_phase(w, m, v, ls, uloc, counts, overflow, g_slots, g_full,
     def sparse_branch(_):
         with jax.named_scope("row_gather_catchup"):
             w_rows, m_rows, v_rows = cc_ops.sparse_gather_catchup(
-                w, m, v, ls, uloc, t, use_kernel=use_kernel, **adam_kw)
+                w, m, v, ls, uloc, t, **adam_kw)
         g_rows = g_slots if g_slots is not None else g_full[safe]
         with jax.named_scope("row_update_scatter"):
             return cc_ops.sparse_update_scatter(
                 w, m, v, ls, uloc, counts, w_rows, g_rows, m_rows, v_rows, t,
-                r=r, zeta=zeta, clip=clip, use_kernel=use_kernel, **adam_kw)
+                r=r, zeta=zeta, clip=clip, **adam_kw)
 
     if overflow is False:
         return sparse_branch(None)

@@ -8,7 +8,7 @@ rows live and how their optimizer update runs:
                 the whole table every step (O(vocab)). Exactness oracle.
                 ``kernel="substrate"`` runs the composable
                 GradientTransformation chain, ``kernel="fused"`` the fused
-                Pallas CowClip+L2+Adam kernel per table.
+                jnp CowClip+L2+Adam update per table.
 * ``sparse``  — unique-id gather -> fused row update -> scatter with lazy
                 L2 decay (O(batch) update traffic). One device, vocab-bound
                 memory but batch-bound compute.
@@ -157,15 +157,9 @@ class EmbeddingStore:
         b1: float = 0.9,
         b2: float = 0.999,
         eps: float = 1e-8,
-        use_kernel: bool = False,
         nonfinite_guard: bool = False,
     ) -> TrainStepBundle:
         """Build this placement's (step, init, flush, prepare) bundle.
-
-        ``use_kernel=True`` runs the embedding row update through the Pallas
-        kernels (repro.kernels.cowclip) on the placements that have them
-        (fused, sparse, sharded_sparse); the default is the jnp/XLA update
-        on every backend.
 
         ``nonfinite_guard`` wraps the step so a batch whose loss comes out
         NaN/Inf skips the entire update (params, moments, step counter),
@@ -175,8 +169,7 @@ class EmbeddingStore:
         """
         bundle = self._build_bundle(
             cfg, hp, clip_kind=clip_kind, r=r, zeta=zeta, clip_t=clip_t,
-            warmup_steps=warmup_steps, b1=b1, b2=b2, eps=eps,
-            use_kernel=use_kernel)
+            warmup_steps=warmup_steps, b1=b1, b2=b2, eps=eps)
         if nonfinite_guard:
             bundle = guard_bundle(bundle)
         return bundle
@@ -194,7 +187,6 @@ class EmbeddingStore:
         b1: float = 0.9,
         b2: float = 0.999,
         eps: float = 1e-8,
-        use_kernel: bool = False,
     ) -> TrainStepBundle:
         from ..train import loop as loop_lib  # deferred: train imports core
 
@@ -209,10 +201,9 @@ class EmbeddingStore:
         dense_tx = builders.dense_tower_tx(
             hp, warmup_steps=warmup_steps, b1=b1, b2=b2, eps=eps)
 
-        if self.placement == "dense":   # fused kernel
+        if self.placement == "dense":   # fused update
             step, init = loop_lib.make_fused_train_step(
-                cfg, hp, r=r, zeta=zeta, dense_tx=dense_tx,
-                use_kernel=use_kernel)
+                cfg, hp, r=r, zeta=zeta, dense_tx=dense_tx)
             return TrainStepBundle(step, init, builders.identity_flush,
                                    scan_step=step.scan_step)
 
@@ -225,8 +216,7 @@ class EmbeddingStore:
         if self.placement == "sparse":
             step, init, flush = loop_lib.make_sparse_train_step(
                 cfg, hp, r=r, zeta=zeta, dense_tx=dense_tx,
-                use_kernel=use_kernel, clip=clip_kind == "adaptive_column",
-                b1=b1, b2=b2, eps=eps)
+                clip=clip_kind == "adaptive_column", b1=b1, b2=b2, eps=eps)
             return TrainStepBundle(step, init, flush,
                                    scan_step=step.scan_step)
 
@@ -246,9 +236,9 @@ class EmbeddingStore:
 
             step, init, flush = hotcold_lib.make_hotcold_train_step(
                 cfg, hp, capacity=self.hot_capacity, r=r, zeta=zeta,
-                dense_tx=dense_tx, use_kernel=use_kernel,
-                clip=clip_kind == "adaptive_column", b1=b1, b2=b2, eps=eps,
-                admission=self.admission, half_life=self.half_life)
+                dense_tx=dense_tx, clip=clip_kind == "adaptive_column",
+                b1=b1, b2=b2, eps=eps, admission=self.admission,
+                half_life=self.half_life)
             return TrainStepBundle(step, init, flush,
                                    scan_step=step.scan_step)
 
@@ -261,9 +251,8 @@ class EmbeddingStore:
             step, init, flush, prepare, export = (
                 loop_lib.make_sharded_sparse_train_step(
                     cfg, hp, mesh, scheme=self.partition, r=r, zeta=zeta,
-                    dense_tx=dense_tx, use_kernel=use_kernel,
-                    clip=clip_kind == "adaptive_column", b1=b1, b2=b2,
-                    eps=eps))
+                    dense_tx=dense_tx, clip=clip_kind == "adaptive_column",
+                    b1=b1, b2=b2, eps=eps))
         else:
             step, init, flush, prepare, export = (
                 loop_lib.make_sharded_train_step(
@@ -356,8 +345,8 @@ def store_for(
     path = resolve_path(cfg, path)
     placement, kernel = _PATH_TO_STORE[path]
     if placement == "dense" and kernel == "fused" and getattr(cfg, "sparse", False):
-        # the fused entry point honors the knob and would delegate anyway;
-        # route here so the bundle carries the sparse flush
+        # the one router: the fused step is dense only, and the sparse
+        # placement's bundle carries its flush
         placement, kernel = "sparse", "auto"
     return EmbeddingStore(placement=placement, kernel=kernel, mesh=mesh,
                           partition=partition, hot_capacity=hot_capacity,

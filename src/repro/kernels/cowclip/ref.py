@@ -1,17 +1,19 @@
-"""Pure-jnp oracles for the fused CowClip+L2+Adam kernels (dense + sparse).
+"""The fused CowClip+L2+Adam row update (dense + sparse) in jnp.
 
-Composes the framework's own building blocks (``core.cowclip.cowclip_table``
-+ coupled L2 + Adam with bias correction) so the kernels are checked against
-the exact math the optimizer substrate uses. Rows absent from the batch
-(``cnt == 0``) take one geometric L2 decay step — ``w *= 1 - lr*l2`` with
-the Adam moments held — matching ``core.optim.lazy_coupled_adam``. The
-sparse oracles additionally compose ``core.optim.decay_catchup_rows`` /
+This is the embedding row update every placement runs, on every backend:
+``ops`` jits these functions and XLA compiles them. They compose the
+framework's own building blocks (``core.cowclip.cowclip_table`` + coupled
+L2 + Adam with bias correction), so the update is the exact math the
+optimizer substrate uses. Rows absent from the batch (``cnt == 0``) take
+one geometric L2 decay step — ``w *= 1 - lr*l2`` with the Adam moments
+held — matching ``core.optim.lazy_coupled_adam``. The sparse functions
+additionally compose ``core.optim.decay_catchup_rows`` /
 ``sparse_adam_rows`` — the closed-form lazy-decay semantics the unique-id
 path must preserve.
 
 The sparse path's moment tables may be stored packed (``pack_rows``): the
-row functions here and in ``sparse`` read and write either form, telling
-them apart by shape.
+row functions here read and write either form, telling them apart by
+shape.
 """
 
 from __future__ import annotations

@@ -130,8 +130,7 @@ def make_ctr_config(args, ds, placement) -> ctr_lib.CTRConfig:
     )
 
 
-def make_ctr_bundle(args, cfg, store, n_train: int, *,
-                    use_kernel: bool = False):
+def make_ctr_bundle(args, cfg, store, n_train: int):
     """The run's train-step bundle: the ``--rule`` scaled hyperparameters
     and the CowClip clip (rule cowclip) through ``store``'s placement."""
     hp = scale_hyperparams(
@@ -142,8 +141,7 @@ def make_ctr_bundle(args, cfg, store, n_train: int, *,
     clip = "adaptive_column" if args.rule == "cowclip" else "none"
     return store.make_bundle(cfg, hp, clip_kind=clip, zeta=args.zeta,
                              warmup_steps=max(1, n_train // args.batch),
-                             nonfinite_guard=args.nonfinite_guard,
-                             use_kernel=use_kernel)
+                             nonfinite_guard=args.nonfinite_guard)
 
 
 def run_ctr(args, data=None):

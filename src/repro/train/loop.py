@@ -90,31 +90,19 @@ def _uniq_tree(embed_params: dict, uniq: dict) -> dict:
 
 
 def make_fused_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
-                          zeta: float = 1e-5, dense_tx=None,
-                          use_kernel: bool = False):
-    """Train step that runs every embedding table through one fused
-    CowClip+L2+Adam update per table (repro.kernels.cowclip) instead of the
-    composable transform chain: its jnp form by default, the Pallas kernel
-    with ``use_kernel=True``. Dense tower still goes through the substrate
-    optimizer. State: {"step", "m", "v"} trees for embeddings + the dense
-    transform state.
+                          zeta: float = 1e-5, dense_tx=None):
+    """Dense train step that runs every embedding table through one fused
+    CowClip+L2+Adam update per table (``kernels.cowclip.fused_cowclip_adam``)
+    instead of the composable transform chain. Dense tower still goes
+    through the substrate optimizer. State: {"step", "m", "v"} trees for
+    embeddings + the dense transform state.
 
-    With ``cfg.sparse`` this routes to ``make_sparse_train_step`` (the
-    unique-id gather -> fused-update -> scatter path) and returns its full
-    ``(step, init, flush)`` triple — the sparse contract requires flushing
-    pending lazy decay before eval/checkpoint, so the flush is deliberately
-    not droppable (``step, init = ...`` unpacking fails loudly rather than
-    silently skipping it). The dense layout here is retained as the sparse
-    path's exactness oracle; equivalence of all paths is asserted in
-    tests/test_train_integration.py and tests/test_sparse_embedding.py.
+    Always the dense layout: ``embed.store.store_for`` sends a ``fused``
+    request with ``cfg.sparse`` to the sparse placement. Equivalence with
+    the substrate is asserted in tests/test_train_integration.py.
     """
     from ..core import optim as optim_lib
     from ..kernels.cowclip import fused_cowclip_adam
-
-    if cfg.sparse:
-        return make_sparse_train_step(cfg, hp, r=r, zeta=zeta,
-                                      dense_tx=dense_tx,
-                                      use_kernel=use_kernel)
 
     if dense_tx is None:
         dense_tx = optim_lib.adam(hp.dense_lr, l2=hp.dense_l2)
@@ -137,12 +125,12 @@ def make_fused_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
         counts = ctr.batch_counts(cfg, batch["ids"], params)
         t = state["step"] + 1
 
-        # 1-dim LR tables are CowClip-exempt but share the kernel
-        # (the kernel itself skips clipping when dim < 2).
+        # 1-dim LR tables are CowClip-exempt but share the update
+        # (cowclip_table skips clipping when dim < 2).
         out = jax.tree.map(
             lambda w, g, c, m, v: fused_cowclip_adam(
                 w, g, c, m, v, t, r=r, zeta=zeta,
-                lr=hp.emb_lr, l2=hp.emb_l2, use_kernel=use_kernel),
+                lr=hp.emb_lr, l2=hp.emb_l2),
             params["embed"], grads["embed"], counts, state["m"], state["v"],
         )
         new_embed, new_m, new_v = _unzip3(out, params["embed"])
@@ -160,15 +148,14 @@ def make_fused_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
 
 def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
                            zeta: float = 1e-5, dense_tx=None,
-                           use_kernel: bool = False, clip: bool = True,
-                           b1: float = 0.9, b2: float = 0.999,
-                           eps: float = 1e-8):
+                           clip: bool = True, b1: float = 0.9,
+                           b2: float = 0.999, eps: float = 1e-8):
     """The sparse unique-id train step: per step, each field's batch ids are
     deduplicated once and the embedding update runs entirely on the
     ``[n_unique, dim]`` gathered rows — gather -> lazy-L2-decay catch-up ->
     forward/backward on rows -> CowClip -> Adam -> scatter. Update HBM
-    traffic is O(batch), not O(vocab). The row math is jnp by default, the
-    Pallas row kernels (repro.kernels.cowclip.sparse) with ``use_kernel``.
+    traffic is O(batch), not O(vocab). The row math is
+    ``kernels.cowclip``'s jnp, compiled by XLA.
 
     Ids absent from a batch are not touched; their coupled-L2 decay accrues
     in a per-row ``last_step`` array and is replayed on next touch (or by
@@ -238,7 +225,7 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
         with jax.named_scope("row_gather_catchup"):
             caught = jax.tree.map(
                 lambda u, w, m, v, ls: cc_kernels.sparse_gather_catchup(
-                    w, m, v, ls, u.uids, t, use_kernel=use_kernel, **adam_kw),
+                    w, m, v, ls, u.uids, t, **adam_kw),
                 utree, params["embed"], state["m"], state["v"],
                 state["last_step"], is_leaf=_is_uniq,
             )
@@ -257,8 +244,7 @@ def make_sparse_train_step(cfg: ctr.CTRConfig, hp, *, r: float = 1.0,
                 lambda u, w, m, v, ls, wr, gr, mr, vr:
                 cc_kernels.sparse_update_scatter(
                     w, m, v, ls, u.uids, u.counts, wr, gr, mr, vr, t,
-                    r=r, zeta=zeta, use_kernel=use_kernel, clip=clip,
-                    **adam_kw),
+                    r=r, zeta=zeta, clip=clip, **adam_kw),
                 utree, params["embed"], state["m"], state["v"],
                 state["last_step"], w_rows, g_rows, m_rows, v_rows,
                 is_leaf=_is_uniq,
@@ -479,7 +465,6 @@ def _warn_overflow(n, t):
 def make_sharded_sparse_train_step(cfg: ctr.CTRConfig, hp, mesh, *,
                                    scheme: str = "div", r: float = 1.0,
                                    zeta: float = 1e-5, dense_tx=None,
-                                   use_kernel: bool = False,
                                    clip: bool = True, b1: float = 0.9,
                                    b2: float = 0.999, eps: float = 1e-8):
     """The sharded+sparse hybrid train step: tables row-sharded over
@@ -679,8 +664,7 @@ def make_sharded_sparse_train_step(cfg: ctr.CTRConfig, hp, mesh, *,
                      new_ls[group][f]) = hybrid_lib.update_phase(
                         embed_sh[group][f], m_sh[group][f], v_sh[group][f],
                         ls_sh[group][f], uloc, cnts, ovf,
-                        g_slots, g_full, cnt_full[f], t,
-                        use_kernel=use_kernel, **upd_kw)
+                        g_slots, g_full, cnt_full[f], t, **upd_kw)
         return new_w, new_m, new_v, new_ls, g_dense, loss, n_overflow, depth
 
     # check_vma=False: each model shard updates its rows from the dedup of

@@ -1,6 +1,6 @@
 """Closed-form lazy-decay catch-up: property tests against the iterative
-replay oracle, the schedule fallback, the Pallas kernel path with a shard
-row offset, and the depth-10_000 first-touch regression.
+replay oracle, the schedule fallback, the row gather with a shard row
+offset, and the depth-10_000 first-touch regression.
 
 The contract: ``core.optim.decay_catchup_rows`` collapses k pending
 decay-only steps into one multiply ``w *= (1 - lr*l2)**k`` (O(1) in k), and
@@ -25,7 +25,7 @@ except ImportError:
 
 from repro.core import optim as optim_lib
 from repro.kernels.cowclip import ref as cc_ref
-from repro.kernels.cowclip import sparse as cc_sparse
+from repro.kernels.cowclip import sparse_gather_catchup
 
 
 def _rows(rng, n, dim, scale=1e-2):
@@ -155,7 +155,7 @@ def test_constant_valued_schedule_exact_at_any_depth():
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel path (interpret mode) with a shard row offset
+# the row gather with a shard row offset
 # ---------------------------------------------------------------------------
 
 
@@ -167,9 +167,9 @@ def test_constant_valued_schedule_exact_at_any_depth():
 )
 def test_kernel_catchup_matches_replay_with_row_offset(depth, row_offset,
                                                        seed):
-    """The sparse_gather_catchup kernel fed global uids against one row
-    shard (the sharded_sparse calling convention) matches the iterative
-    replay of the gathered rows at any pending depth."""
+    """``sparse_gather_catchup`` fed global uids against one row shard (the
+    sharded_sparse calling convention) matches the iterative replay of the
+    gathered rows at any pending depth."""
     rng = np.random.default_rng(seed)
     rows, dim, cap = 16, 8, 6
     lr, l2 = 1e-3, 1e-2
@@ -182,9 +182,8 @@ def test_kernel_catchup_matches_replay_with_row_offset(depth, row_offset,
     uids = jnp.asarray(np.sort(local) + row_offset)
     step = jnp.asarray(depth, jnp.int32)
 
-    w_k, m_k, v_k = cc_sparse.sparse_gather_catchup(
-        w, m, v, ls, uids, step, lr=lr, l2=l2, row_offset=row_offset,
-        interpret=True)
+    w_k, m_k, v_k = sparse_gather_catchup(
+        w, m, v, ls, uids, step, lr=lr, l2=l2, row_offset=row_offset)
 
     loc = np.asarray(uids) - row_offset
     w_rp = optim_lib.decay_replay_reference(w[loc], ls[loc], step - 1,
@@ -193,10 +192,6 @@ def test_kernel_catchup_matches_replay_with_row_offset(depth, row_offset,
                                atol=1e-5, rtol=0)
     np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m)[loc])
     np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v)[loc])
-    # the jnp oracle agrees with the kernel bit-for-bit on real slots
-    w_r, _, _ = cc_ref.sparse_gather_catchup_reference(
-        w, m, v, ls, uids, step, lr=lr, l2=l2, row_offset=row_offset)
-    np.testing.assert_array_equal(np.asarray(w_k), np.asarray(w_r))
 
 
 # ---------------------------------------------------------------------------
@@ -231,8 +226,8 @@ def test_first_touch_at_step_10000_matches_dense_run():
 
     # sparse placement: one closed-form catch-up at first touch
     uids = jnp.arange(vocab, dtype=jnp.int32)[:8]
-    w_rows, m_rows, v_rows = cc_sparse.sparse_gather_catchup(
-        w, m, v, ls, uids, t, lr=lr, l2=l2, interpret=True)
+    w_rows, m_rows, v_rows = sparse_gather_catchup(
+        w, m, v, ls, uids, t, lr=lr, l2=l2)
 
     np.testing.assert_allclose(np.asarray(w_rows), np.asarray(w_dense)[:8],
                                atol=1e-5, rtol=0)
@@ -276,7 +271,7 @@ def test_sparse_aux_reports_catchup_depth():
                         sparse=True)
     hp = scale_hyperparams("cowclip", base_lr=1e-3, base_l2=1e-3,
                            base_batch=16, batch_size=16, base_dense_lr=2e-3)
-    bundle = build_train_step(cfg, hp, path="sparse", use_kernel=False)
+    bundle = build_train_step(cfg, hp, path="sparse")
     params = bundle.prepare(ctr.init(jax.random.key(0), cfg))
     state = bundle.init(params)
     depths = []
@@ -300,8 +295,7 @@ def test_sharded_sparse_aux_reports_catchup_depth():
     hp = scale_hyperparams("cowclip", base_lr=1e-3, base_l2=1e-3,
                            base_batch=16, batch_size=16, base_dense_lr=2e-3)
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    bundle = build_train_step(cfg, hp, path="sharded_sparse", mesh=mesh,
-                              use_kernel=False)
+    bundle = build_train_step(cfg, hp, path="sharded_sparse", mesh=mesh)
     params = bundle.prepare(ctr.init(jax.random.key(1), cfg))
     state = bundle.init(params)
     depths = []

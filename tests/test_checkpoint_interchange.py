@@ -69,7 +69,7 @@ def _bundle(path):
     mesh = (jax.make_mesh((1, 1), ("data", "model"))
             if path in SHARDED else None)
     return build_train_step(_cfg(), _hp(), path=path, mesh=mesh,
-                            use_kernel=False, hot_capacity=8)
+                            hot_capacity=8)
 
 
 @functools.lru_cache(maxsize=None)

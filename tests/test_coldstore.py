@@ -68,7 +68,7 @@ def _batches(seed=1):
 
 
 def _bundle(capacity, **kw):
-    return build_train_step(_cfg(), _hp(), path="hotcold", use_kernel=False,
+    return build_train_step(_cfg(), _hp(), path="hotcold",
                             hot_capacity=capacity, **kw)
 
 

@@ -65,6 +65,26 @@ def test_unknown_path_and_placement_rejected():
     assert "sharded" in TRAIN_PATHS
 
 
+def test_use_kernel_accepts_false_only():
+    """``build_train_step`` keeps ``use_kernel`` for callers that still pass
+    it: ``True`` is refused, and ``False`` builds the same step as leaving
+    it out."""
+    cfg = _cfg(sparse=True)
+    with pytest.raises(ValueError, match="Pallas CowClip row kernels"):
+        build_train_step(cfg, _hp(), use_kernel=True)
+    params = jax.eval_shape(lambda: ctr.init(jax.random.key(0), cfg))
+    batch = {"ids": jax.ShapeDtypeStruct((32, len(VOCABS)), jnp.int32),
+             "dense": jax.ShapeDtypeStruct((32, 3), jnp.float32),
+             "labels": jax.ShapeDtypeStruct((32,), jnp.float32)}
+    texts = []
+    for kw in ({}, {"use_kernel": False}):
+        bundle = build_train_step(cfg, _hp(), **kw)
+        state = jax.eval_shape(bundle.init, params)
+        texts.append(jax.jit(bundle.scan_step).lower(
+            params, state, batch).as_text())
+    assert texts[0] == texts[1]
+
+
 def test_sparse_placement_rejects_ablation_clips():
     with pytest.raises(ValueError, match="substrate-only"):
         build_train_step(_cfg(sparse=True), _hp(), clip_kind="global")
@@ -88,8 +108,7 @@ def test_describe_names_the_placement():
 
 @pytest.mark.parametrize("path", ["substrate", "fused", "sparse"])
 def test_non_sharded_bundles_prepare_is_identity(path):
-    bundle = build_train_step(_cfg(sparse=path == "sparse"), _hp(), path=path,
-                              use_kernel=False)
+    bundle = build_train_step(_cfg(sparse=path == "sparse"), _hp(), path=path)
     assert isinstance(bundle, TrainStepBundle)
     assert bundle.prepare is identity_prepare
     params = ctr.init(jax.random.key(0), _cfg())
@@ -103,7 +122,7 @@ def test_flush_idempotent_after_train_ctr_sparse():
     cfg = _cfg(sparse=True)
     ds = make_ctr_dataset(2000, VOCABS, n_dense=3, zipf_a=1.2, seed=0)
     tr, te = ds.split(0.9)
-    bundle = build_train_step(cfg, _hp(), use_kernel=False)
+    bundle = build_train_step(cfg, _hp())
     res = train_ctr(cfg, None, tr, te, batch_size=128, epochs=1, seed=0,
                     step_bundle=bundle)
     assert res.params is not None and res.opt_state is not None
@@ -121,8 +140,7 @@ def test_flush_identity_for_eager_paths(path):
     cfg = _cfg()
     mesh = (jax.make_mesh((1, 1), ("data", "model"))
             if path == "sharded" else None)
-    bundle = build_train_step(cfg, _hp(), path=path, mesh=mesh,
-                              use_kernel=False)
+    bundle = build_train_step(cfg, _hp(), path=path, mesh=mesh)
     params = bundle.prepare(ctr.init(jax.random.key(0), cfg))
     state = bundle.init(params)
     p2, s2 = bundle.flush(params, state)

@@ -74,8 +74,7 @@ def _run(path, capacity=0, seed=1, admission="cumulative", half_life=0):
 
     kw = ({"hot_capacity": capacity, "admission": admission,
            "half_life": half_life} if path == "hotcold" else {})
-    bundle = build_train_step(_cfg(), _hp(), path=path, use_kernel=False,
-                              **kw)
+    bundle = build_train_step(_cfg(), _hp(), path=path, **kw)
     params = bundle.prepare(ctr.init(jax.random.key(0), _cfg()))
     state = bundle.init(params)
     auxes = []

@@ -107,11 +107,10 @@ def test_scatter_packed_sets_rows_exactly(dim):
                                    rtol=1e-6)
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_row_functions_read_either_form(use_kernel):
-    """``sparse_gather_catchup`` / ``sparse_update_scatter`` (jnp oracle and
-    Pallas pair) give the same rows and tables, bit for bit, whether the
-    moments come packed or ``[V, dim]``."""
+def test_row_functions_read_either_form():
+    """``sparse_gather_catchup`` / ``sparse_update_scatter`` give the same
+    rows and tables, bit for bit, whether the moments come packed or
+    ``[V, dim]``."""
     vocab, dim = 61, 10
     w, m, v = (_table(vocab, dim, seed=s) for s in (4, 5, 6))
     v = jnp.abs(v)
@@ -120,7 +119,7 @@ def test_row_functions_read_either_form(use_kernel):
     uids, counts = _slots(vocab, dim, 24, seed=4)
     g = _table(24, dim, seed=8)
     t = jnp.int32(4)
-    kw = dict(lr=1e-2, l2=1e-3, use_kernel=use_kernel)
+    kw = dict(lr=1e-2, l2=1e-3)
     outs = []
     for form in (jnp.copy, cc_ref.pack_rows):
         rows = sparse_gather_catchup(w, form(m), form(v), ls, uids, t, **kw)

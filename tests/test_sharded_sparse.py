@@ -27,7 +27,7 @@ from repro.core import build_optimizer, build_train_step, scale_hyperparams
 from repro.embed import EmbeddingStore, store_for
 from repro.embed.sharded import RowShardPlan
 from repro.embed.sharded_sparse import shard_capacity, shard_unique_sets
-from repro.kernels.cowclip import ref as cc_ref, sparse as cc_sparse
+from repro.kernels.cowclip import ops as cc_ops
 from repro.launch.train import resolve_placement
 from repro.models import ctr
 from repro.train.loop import make_train_step
@@ -258,15 +258,15 @@ def test_overflow_mid_run_falls_back_dense_and_stays_exact():
 
 
 # ---------------------------------------------------------------------------
-# shard-offset-aware kernels vs oracle
+# shard-offset row functions vs the local-id form
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("dim", [8, 1])
 def test_sparse_kernels_row_offset_match_oracle(dim):
     """The row_offset form: global uids against a mid-table row-shard
-    window, interpret-mode kernels vs the jnp oracle vs the local-id path
-    (dim=1 exercises the CowClip-exempt LR stream)."""
+    window give the rows and tables of the local-id form, whose pads fall
+    out of range (dim=1 exercises the CowClip-exempt LR stream)."""
     vocab, cap = 50, 6
     rows, off = 15, 15          # shard window: global rows 15..29
     ks = jax.random.split(jax.random.key(0), 6)
@@ -283,65 +283,28 @@ def test_sparse_kernels_row_offset_match_oracle(dim):
     kw = dict(lr=1e-3, l2=1e-4)
     n_real = int((cnt > 0).sum())
 
-    w_sh, m_sh, v_sh = w[off:off + rows], m[off:off + rows], v[off:off + rows]
-    ls_sh = ls[off:off + rows]
-
-    ref_rows = cc_ref.sparse_gather_catchup_reference(
-        w_sh, m_sh, v_sh, ls_sh, uids, t, row_offset=off, **kw)
-    # oracle with pre-localized ids agrees (pads vocab-off=35 out of range)
-    loc_rows = cc_ref.sparse_gather_catchup_reference(
-        w_sh, m_sh, v_sh, ls_sh, uids - off, t, **kw)
-    k_rows = cc_sparse.sparse_gather_catchup(
-        w_sh, m_sh, v_sh, ls_sh, uids, t, row_offset=off, interpret=True,
-        **kw)
-    for a, b, c in zip(ref_rows, loc_rows, k_rows):
+    shard = [a[off:off + rows] for a in (w, m, v, ls)]
+    off_rows = cc_ops.sparse_gather_catchup(*shard, uids, t, row_offset=off,
+                                            **kw)
+    # pre-localized ids: the pads (vocab - off = 35) are out of range
+    loc_rows = cc_ops.sparse_gather_catchup(*shard, uids - off, t, **kw)
+    for a, b in zip(off_rows, loc_rows):
         np.testing.assert_array_equal(np.asarray(a)[:n_real],
                                       np.asarray(b)[:n_real])
-        np.testing.assert_allclose(np.asarray(a)[:n_real],
-                                   np.asarray(c)[:n_real], atol=1e-6)
 
-    ref_out = cc_ref.sparse_update_scatter_reference(
-        w_sh, m_sh, v_sh, ls_sh, uids, cnt, ref_rows[0], g_rows,
-        ref_rows[1], ref_rows[2], t, row_offset=off, **kw)
-    k_out = cc_sparse.sparse_update_scatter(
-        jnp.copy(w_sh), jnp.copy(m_sh), jnp.copy(v_sh), uids, cnt,
-        ref_rows[0], g_rows, ref_rows[1], ref_rows[2], t, row_offset=off,
-        interpret=True, **kw)
-    for a, b in zip(ref_out[:3], k_out):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
+    def update(ids, **offset):
+        return cc_ops.sparse_update_scatter(
+            *(jnp.copy(a) for a in shard), ids, cnt, off_rows[0], g_rows,
+            off_rows[1], off_rows[2], t, **offset, **kw)
+
+    off_out = update(uids, row_offset=off)
+    for a, b in zip(off_out, update(uids - off)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # rows outside the unique set are untouched on the shard
     unset = np.setdiff1d(np.arange(rows), np.asarray(uids[:n_real]) - off)
-    np.testing.assert_array_equal(np.asarray(ref_out[0])[unset],
-                                  np.asarray(w_sh)[unset])
-
-
-def test_hybrid_kernel_path_matches_dense_1x1():
-    """use_kernel=True routes the per-shard catch-up/update through the
-    Pallas row kernels (interpret mode on CPU) inside the shard_map; a tiny
-    config keeps interpret-mode cost down."""
-    cfg = ctr.CTRConfig(name="dcn", vocab_sizes=(20, 7), n_dense=2,
-                        emb_dim=4, mlp_dims=(8, 8, 8), emb_sigma=1e-2)
-    hp = scale_hyperparams("cowclip", base_lr=1e-3, base_l2=1e-3,
-                           base_batch=8, batch_size=8, base_dense_lr=2e-3)
-    dstep, dparams, dstate, params0 = _dense_oracle(cfg, hp)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    store = EmbeddingStore(placement="sharded_sparse", mesh=mesh)
-    bundle = store.make_bundle(cfg, hp, warmup_steps=0, use_kernel=True)
-    sparams = bundle.prepare(jax.tree.map(jnp.copy, params0))
-    sstate = bundle.init(sparams)
-
-    rng = np.random.default_rng(0)
-    for _ in range(2):
-        ids = np.stack([rng.integers(0, 20, size=8),
-                        rng.integers(0, 7, size=8)], axis=1).astype(np.int32)
-        b = {"ids": jnp.asarray(ids),
-             "dense": jnp.asarray(rng.normal(size=(8, 2)).astype(np.float32)),
-             "labels": jnp.asarray((rng.random(8) < 0.3).astype(np.float32))}
-        dparams, dstate, da = dstep(dparams, dstate, dict(b))
-        sparams, sstate, sa = bundle.step(sparams, sstate, dict(b))
-        assert float(da["loss"]) == pytest.approx(float(sa["loss"]), rel=1e-5)
-    sparams, sstate = bundle.flush(sparams, sstate)
-    assert _max_err(dparams, bundle.export(sparams)) <= 1e-5
+    for after, before in zip(off_out, shard):
+        np.testing.assert_array_equal(np.asarray(after)[unset],
+                                      np.asarray(before)[unset])
 
 
 # ---------------------------------------------------------------------------

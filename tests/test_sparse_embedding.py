@@ -1,5 +1,6 @@
 """Sparse unique-id embedding update path: dense-vs-sparse exactness,
-lazy-L2-decay catch-up, capacity overflow, and kernel-vs-oracle agreement.
+lazy-L2-decay catch-up, capacity overflow, and the row functions' pad
+slots.
 
 The contract under test: a sparse train step (unique -> gather -> lazy-decay
 catch-up -> forward on rows -> CowClip -> L2 -> Adam -> scatter) followed by
@@ -19,7 +20,6 @@ from repro.core import build_optimizer, build_train_step, scale_hyperparams
 from repro.core import optim as optim_lib
 from repro.kernels.cowclip import (
     ref as cc_ref,
-    sparse as cc_sparse,
     sparse_gather_catchup,
     sparse_update_scatter,
 )
@@ -169,12 +169,10 @@ def test_sparse_forward_equals_dense_forward():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
-def test_sparse_step_matches_dense_substrate_10_steps(use_kernel):
+def test_sparse_step_matches_dense_substrate_10_steps():
     """>= 10 steps with duplicate-heavy batches and long-absent ids: flushed
     sparse params must be bitwise-close (atol 1e-5 f32) to the dense path."""
-    n_steps = 4 if use_kernel else 12   # interpret-mode kernels are slow
-    batch = 16 if use_kernel else 32
+    n_steps, batch = 12, 32
     cfg_d = _cfg()
     cfg_s = dataclasses.replace(cfg_d, sparse=True)
     hp = _hp()
@@ -183,8 +181,7 @@ def test_sparse_step_matches_dense_substrate_10_steps(use_kernel):
     tx = build_optimizer(hp, warmup_steps=0)
     dstate = tx.init(params)
     dstep = make_train_step(cfg_d, tx)
-    sstep, sinit, sflush = make_sparse_train_step(cfg_s, hp,
-                                                  use_kernel=use_kernel)
+    sstep, sinit, sflush = make_sparse_train_step(cfg_s, hp)
     dparams = jax.tree.map(jnp.copy, params)
     sparams = jax.tree.map(jnp.copy, params)
     sstate = sinit(sparams)
@@ -279,7 +276,7 @@ def test_lazy_path_exact_at_zero_l2():
     tx = build_optimizer(hp, warmup_steps=0)
     dstate = tx.init(params)
     dstep = make_train_step(cfg_d, tx)
-    sstep, sinit, sflush = make_sparse_train_step(cfg_s, hp, use_kernel=False)
+    sstep, sinit, sflush = make_sparse_train_step(cfg_s, hp)
     dparams = jax.tree.map(jnp.copy, params)
     sparams = jax.tree.map(jnp.copy, params)
     sstate = sinit(sparams)
@@ -297,7 +294,7 @@ def test_untouched_rows_not_written_until_flush():
     cfg = _cfg(sparse=True)
     hp = _hp()
     params = ctr.init(jax.random.key(1), cfg)
-    step, init, _ = make_sparse_train_step(cfg, hp, use_kernel=False)
+    step, init, _ = make_sparse_train_step(cfg, hp)
     state = init(params)
     before = np.asarray(params["embed"]["fm"]["field_0"]).copy()
 
@@ -319,7 +316,7 @@ def test_flush_passes_moments_through():
     the embedding state on the device at Criteo widths."""
     cfg = _cfg(sparse=True)
     params = ctr.init(jax.random.key(1), cfg)
-    step, init, flush = make_sparse_train_step(cfg, _hp(), use_kernel=False)
+    step, init, flush = make_sparse_train_step(cfg, _hp())
     params, state, _ = step(params, init(params),
                             next(_dup_heavy_batches(1, seed=2)))
     _, flushed = flush(params, state)
@@ -343,7 +340,7 @@ def test_unique_capacity_overflow_documented_behavior():
     cfg = _cfg(sparse=True, unique_capacity=3)  # field 0 sees 5 unique ids
     hp = _hp()
     params = ctr.init(jax.random.key(4), cfg)
-    step, init, flush = make_sparse_train_step(cfg, hp, use_kernel=False)
+    step, init, flush = make_sparse_train_step(cfg, hp)
     state = init(params)
     before = np.asarray(params["embed"]["fm"]["field_0"]).copy()
 
@@ -370,49 +367,12 @@ def test_unique_capacity_overflow_documented_behavior():
 
 
 # ---------------------------------------------------------------------------
-# kernels vs jnp oracle
+# row functions: pad slots
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("dim", [8, 1])
-def test_sparse_kernels_match_reference(dim):
-    """Interpret-mode Pallas kernels vs the jnp oracle, with pad slots and
-    per-row catch-up depths (dim=1 exercises the CowClip-exempt LR path)."""
-    vocab, cap = 50, 12
-    ks = jax.random.split(jax.random.key(0), 6)
-    w = 0.01 * jax.random.normal(ks[0], (vocab, dim))
-    m = 0.001 * jax.random.normal(ks[1], (vocab, dim))
-    v = 0.0001 * jnp.abs(jax.random.normal(ks[2], (vocab, dim)))
-    ls = jax.random.randint(ks[3], (vocab,), 0, 5)
-    t = jnp.asarray(7, jnp.int32)
-    ids = jnp.array([3, 17, 3, 44, 9, 17, 25, 30, 9, 3, 41, 8])
-    uids, cnt = jnp.unique(ids, size=cap, fill_value=vocab,
-                           return_counts=True)
-    uids, cnt = uids.astype(jnp.int32), cnt.astype(jnp.float32)
-    g_rows = 0.1 * jax.random.normal(ks[4], (cap, dim))
-    kw = dict(lr=1e-3, l2=1e-4)
-    n_real = int((cnt > 0).sum())
-
-    ref_rows = cc_ref.sparse_gather_catchup_reference(w, m, v, ls, uids, t, **kw)
-    k_rows = sparse_gather_catchup(w, m, v, ls, uids, t, use_kernel=True,
-                                   **kw)
-    for a, b in zip(ref_rows, k_rows):
-        np.testing.assert_allclose(np.asarray(a)[:n_real],
-                                   np.asarray(b)[:n_real], atol=1e-6)
-
-    ref_out = cc_ref.sparse_update_scatter_reference(
-        w, m, v, ls, uids, cnt, ref_rows[0], g_rows, ref_rows[1], ref_rows[2],
-        t, **kw)
-    k_out = sparse_update_scatter(
-        jnp.copy(w), jnp.copy(m), jnp.copy(v), jnp.copy(ls), uids, cnt,
-        ref_rows[0], g_rows, ref_rows[1], ref_rows[2], t,
-        use_kernel=True, **kw)
-    for a, b in zip(ref_out, k_out):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
-
-
 def test_sparse_kernel_pad_slots_write_nothing():
-    """Pad slots (count 0) are dropped by the kernel path's scatter even when
+    """Pad slots (count 0) are dropped by the row update's scatter even when
     a pad uid minus the shard's row offset lands inside the shard."""
     rows, dim, off = 40, 8, 20          # shard of global rows 20..59
     ks = jax.random.split(jax.random.key(3), 5)
@@ -425,15 +385,17 @@ def test_sparse_kernel_pad_slots_write_nothing():
     cnt = jnp.array([2.0, 1.0, 0.0, 0.0])
     t = jnp.asarray(4, jnp.int32)
     kw = dict(lr=1e-3, l2=1e-4)
-    w_rows, m_rows, v_rows = cc_sparse.sparse_gather_catchup(
-        w, m, v, jnp.zeros((rows,), jnp.int32), uids, t, row_offset=off,
-        interpret=True, **kw)
+    ls = jnp.zeros((rows,), jnp.int32)
+    w_rows, m_rows, v_rows = sparse_gather_catchup(
+        w, m, v, ls, uids, t, row_offset=off, **kw)
     g_rows = 0.1 * jax.random.normal(ks[3], (4, dim))
-    new = cc_sparse.sparse_update_scatter(
-        jnp.copy(w), jnp.copy(m), jnp.copy(v), uids, cnt, w_rows, g_rows,
-        m_rows, v_rows, t, row_offset=off, interpret=True, **kw)
+    *new, new_ls = sparse_update_scatter(
+        jnp.copy(w), jnp.copy(m), jnp.copy(v), jnp.copy(ls), uids, cnt,
+        w_rows, g_rows, m_rows, v_rows, t, row_offset=off, **kw)
     written = np.array([2, 21])
     kept = np.setdiff1d(np.arange(rows), written)
+    assert (np.asarray(new_ls)[written] == 4).all()
+    assert (np.asarray(new_ls)[kept] == 0).all()
     for before, after in zip((w, m, v), new):
         np.testing.assert_array_equal(np.asarray(after)[kept],
                                       np.asarray(before)[kept])

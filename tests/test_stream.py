@@ -228,7 +228,7 @@ def _run_stream(engine, max_steps=12):
     cfg, hp = _stream_cfg_hp()
     ds = make_ctr_dataset(600, VOCABS, n_dense=3, zipf_a=1.2, seed=9)
     tr, te = ds.split(0.8)
-    bundle = build_train_step(cfg, hp, hot_capacity=16, use_kernel=False)
+    bundle = build_train_step(cfg, hp, hot_capacity=16)
     stream = stream_chunks(
         synthetic_event_stream(tr, events=40, rows_per_event=48, seed=1),
         32, 4)

@@ -1,6 +1,6 @@
 """End-to-end integration: the system trains, CowClip behaves as the paper
-describes, and the fused Pallas kernel is interchangeable with the optimizer
-substrate inside a real train step."""
+describes, and the fused CowClip+L2+Adam update is interchangeable with the
+optimizer substrate inside a real train step."""
 
 import jax
 import jax.numpy as jnp
@@ -80,9 +80,9 @@ def test_train_step_jit_donation(dataset):
     assert np.isfinite(float(aux["loss"]))
 
 
-def test_fused_kernel_equals_substrate_step():
+def test_fused_update_equals_substrate_step():
     """One optimizer step on an embedding table via (a) the composable
-    transform chain and (b) the fused Pallas kernel must agree."""
+    transform chain and (b) the fused update must agree."""
     vocab, dim, batch = 200, 8, 64
     key = jax.random.key(0)
     table = 0.01 * jax.random.normal(key, (vocab, dim))
@@ -105,25 +105,25 @@ def test_fused_kernel_equals_substrate_step():
     w_new, m_new, v_new = fused_cowclip_adam(
         table, g_table, counts["t"], jnp.zeros_like(table),
         jnp.zeros_like(table), jnp.asarray(1, jnp.int32),
-        r=1.0, zeta=1e-5, lr=hp.emb_lr, l2=hp.emb_l2, use_kernel=True,
+        r=1.0, zeta=1e-5, lr=hp.emb_lr, l2=hp.emb_l2,
     )
     np.testing.assert_allclose(np.asarray(w_new), np.asarray(via_substrate),
                                rtol=1e-5, atol=1e-8)
 
-    # and the kernel's moments match the substrate's Adam state
+    # and the fused update's moments match the substrate's Adam state
     emb_state = updates  # recompute state from tx for comparison
     _, new_state = tx.update(grads, state, params, counts=counts)
     adam_state = [s for s in jax.tree.leaves(new_state[0],
                                              is_leaf=lambda x: isinstance(x, ScaleByAdamState))]
-    # structural check only: kernel moments finite and nonzero where ids hit
+    # structural check only: moments finite and nonzero where ids hit
     hit = np.unique(np.asarray(ids))
     assert np.abs(np.asarray(m_new)[hit]).max() > 0
     assert np.isfinite(np.asarray(v_new)).all()
 
 
 def test_fused_train_step_matches_substrate(dataset):
-    """A full DeepFM train step through make_fused_train_step (Pallas kernel
-    path, interpret mode) matches the composable-optimizer step."""
+    """A full DeepFM train step through make_fused_train_step matches the
+    composable-optimizer step."""
     from repro.data import iterate_batches
     from repro.train.loop import make_fused_train_step
 
@@ -138,8 +138,7 @@ def test_fused_train_step_matches_substrate(dataset):
     state = tx.init(params)
     sub_step = make_train_step(cfg, tx)
 
-    fused_step, fused_init = make_fused_train_step(cfg, hp, zeta=1e-5,
-                                                   use_kernel=True)
+    fused_step, fused_init = make_fused_train_step(cfg, hp, zeta=1e-5)
     fstate = fused_init(params)
 
     b = next(iterate_batches(dataset, 512, seed=9))
